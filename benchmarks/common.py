@@ -49,7 +49,10 @@ def write_results(name: str, payload: dict, mode: str | None = None) -> str:
 
 
 def run_with_devices(code: str, n_devices: int = 8, timeout: int = 1200) -> str:
+    """Run a python snippet in a child with N CPU host devices (the child
+    is held to the CPU, so it never contends for a chip the parent holds)."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run(

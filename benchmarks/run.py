@@ -45,6 +45,9 @@ def main() -> None:
     unknown = tags - {tag for tag, _ in BENCHES}
     if unknown:
         sys.exit(f"unknown tags {sorted(unknown)}; run with --help for the list")
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = []
     for tag, module in BENCHES:

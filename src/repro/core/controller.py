@@ -209,6 +209,14 @@ class LiveRController:
         # shard whose only owner died; 0 disables
         self.parity_every = parity_every
         self._parity = None
+        # errors that speculation, prewarm and warm-pool paths swallow by
+        # design (training must go on); one line each, so a caller that
+        # must know — a smoke run, a benchmark gate — can see them
+        self.swallowed_errors: list[str] = []
+        # verification hook: called at every live commit as
+        # fn((params, opt_state) at the cut on the old world,
+        #    (params, opt_state) moved onto the new world, before any update)
+        self.commit_observer: Optional[Callable[[tuple, tuple], None]] = None
 
         # Active World (generation 0). With a pool, every world is built
         # split-step so its update_fn is already warm if it later serves a
@@ -321,7 +329,8 @@ class LiveRController:
             builder = self._spec_builders.pop(key)
             try:
                 handle = builder.result(0)
-            except BaseException:
+            except BaseException as e:
+                self.swallowed_errors.append(f"speculative build: {e!r}")
                 continue
             self.world_pool.put(key, handle)
 
@@ -562,9 +571,10 @@ class LiveRController:
                     f"prewarm done {src_parallel.describe()}"
                     f"->{target.describe()} ahead={ahead}"
                 )
-            except BaseException:
+            except BaseException as e:
                 # speculation must never take down training; the real
                 # transfer will compile (and surface errors) on its own
+                self.swallowed_errors.append(f"transfer prewarm: {e!r}")
                 self._speculation_trace(
                     f"prewarm FAILED {src_parallel.describe()}"
                     f"->{target.describe()} ahead={ahead}\n"
@@ -634,10 +644,11 @@ class LiveRController:
                     handle = self._refresh_pooled(
                         join.result(), mode, source="speculative_join"
                     )
-            except BaseException:
+            except BaseException as e:
                 # speculation must never fail the real resize: a broken
                 # warm/joined world falls back to a fresh cold build (the
                 # taken handle is released, not left pinned until GC)
+                self.swallowed_errors.append(f"warm world refresh: {e!r}")
                 if warm is not None:
                     warm.release()
                 handle = None
@@ -897,8 +908,9 @@ class LiveRController:
             self._commit_armed = True
         elif ready is None:
             # split-step executables unavailable (compile failed): the
-            # reconfiguration still completes — degrade to stop-copy
+            # reconfiguration still completes — degraded to stop-copy
             self._commit_switch()
+            self.records[-1].outcome = "fell_back"
 
     def _grad_fn_ready(self):
         """True = armed, False = still compiling, None = compile failed."""
@@ -913,6 +925,7 @@ class LiveRController:
         if "err" in holder:
             import warnings
 
+            self.swallowed_errors.append(f"split-step grad compile: {holder['err']!r}")
             warnings.warn(
                 "split-step grad compile failed; falling back to stop-copy "
                 f"commit: {holder['err']!r}"
@@ -1095,9 +1108,10 @@ class LiveRController:
             extras, self._extra_shardings(new_world),
             staging_bytes=op_staging,
         )
-        self.params, self.opt_state = rebuild_state(
-            moved, self.params, self.opt_state, new_extras
-        )
+        moved_state = rebuild_state(moved, self.params, self.opt_state, new_extras)
+        if self.commit_observer is not None:
+            self.commit_observer((self.params, self.opt_state), moved_state)
+        self.params, self.opt_state = moved_state
         rec.transfer_s = time.perf_counter() - t0
         rec.moved_bytes = (
             stats.network_bytes + stats.local_bytes + rep_x.moved_bytes
@@ -1208,6 +1222,8 @@ class LiveRController:
         params_new, opt_new = rebuild_state(
             session.results(), self.params, self.opt_state, new_extras
         )
+        if self.commit_observer is not None:
+            self.commit_observer((self.params, self.opt_state), (params_new, opt_new))
         self.params, self.opt_state, om = new_world.update_fn(
             grads_new, opt_new, params_new
         )

@@ -31,11 +31,7 @@ class MockWarmupResult:
 
 
 def abstract_of(mesh: Mesh) -> AbstractMesh:
-    sizes, names = tuple(mesh.devices.shape), tuple(mesh.axis_names)
-    try:
-        return AbstractMesh(sizes, names)  # jax >= 0.5: (axis_sizes, axis_names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))  # 0.4.x: shape_tuple
+    return AbstractMesh(tuple(mesh.devices.shape), tuple(mesh.axis_names))
 
 
 def _retarget(sharding_tree: Any, amesh: AbstractMesh) -> Any:
@@ -61,37 +57,20 @@ def mock_warmup(
     """
     amesh = abstract_of(mesh)
     t0 = time.perf_counter()
-    lowered = None
-    # Prefer the fully device-free AbstractMesh path; jaxlibs without
-    # AbstractMesh lowering support (<=0.4.x raises "_device_assignment is
-    # not implemented") fall back to lowering against the concrete mesh —
-    # still trace+lower only: no executable is loaded and no collective or
-    # device computation runs, which is the property the mock warmup needs.
-    for target in (amesh, mesh):
-        in_sh = _retarget(in_shardings, target)
-        out_sh = _retarget(out_shardings, target) if out_shardings is not None else None
-        jitted = jax.jit(
-            fn,
-            in_shardings=in_sh,
-            out_shardings=out_sh,
-            donate_argnums=donate_argnums,
-            static_argnums=static_argnums,
-        )
-        traced = jitted.trace(*abstract_args)
-        try:
-            try:
-                lowered = traced.lower()
-            except ValueError:
-                # device-less lowering must name its target platform explicitly
-                lowered = traced.lower(lowering_platforms=(jax.default_backend(),))
-            break
-        except (ValueError, NotImplementedError):
-            if target is mesh:
-                raise
-            continue
+    jitted = jax.jit(
+        fn,
+        in_shardings=_retarget(in_shardings, amesh),
+        out_shardings=(
+            _retarget(out_shardings, amesh) if out_shardings is not None else None
+        ),
+        donate_argnums=donate_argnums,
+        static_argnums=static_argnums,
+    )
+    # device-less lowering must name its target platform explicitly
+    lowered = jitted.trace(*abstract_args).lower(
+        lowering_platforms=(jax.default_backend(),)
+    )
     dt = time.perf_counter() - t0
-    try:
-        hlo_bytes = len(lowered.as_text())
-    except Exception:  # pragma: no cover
-        hlo_bytes = 0
-    return MockWarmupResult(lowered=lowered, lower_seconds=dt, hlo_bytes=hlo_bytes)
+    return MockWarmupResult(
+        lowered=lowered, lower_seconds=dt, hlo_bytes=len(lowered.as_text())
+    )

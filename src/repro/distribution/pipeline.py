@@ -10,11 +10,9 @@ through ordinary GSPMD propagation on the other mesh axes — no manual
 (shard_map) region is involved, so the step is a plain differentiable JAX
 function.
 
-(An earlier revision used a partially-manual ``shard_map`` over ``pipe``;
-jax 0.4.x cannot differentiate partially-auto shard_maps — scalar
-residuals break partial-eval and ``ppermute`` crashes the SPMD partitioner
-— and the pure-GSPMD formulation is equivalent math with strictly simpler
-machinery.)
+The schedule is not a partially-manual ``shard_map`` over ``pipe``: the
+pure-GSPMD formulation is equivalent math with simpler machinery, and it
+differentiates like any other JAX function.
 
 Stage layout: the stacked-periods axis of every block tensor is split
 contiguously across stages (requires n_periods % pp == 0) — the same
@@ -224,8 +222,10 @@ def jit_pipeline_train_step(
     ps = merged_pipeline_shardings(cfg, mesh, parallel)
     os_ = {"mu": ps, "nu": ps, "count": NamedSharding(mesh, P())}
     bs = {"tokens": batch_sharding(mesh, global_batch)}
+    from repro.kernels import ops
+
     jitted = jax.jit(
-        train_step,
+        ops.with_kernel_mesh(train_step, mesh),
         in_shardings=(ps, os_, bs),
         out_shardings=(ps, os_, None),
         donate_argnums=(0, 1),

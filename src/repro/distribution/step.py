@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, TrainConfig
-from repro.kernels import reshard_quant
+from repro.kernels import ops, reshard_quant
 from repro.distribution.sharding import (
     batch_sharding,
     cache_shardings,
@@ -227,9 +227,8 @@ def jit_train_step(
         batch_sh["frames"] = bs
     fn = make_train_step(cfg, opt_cfg, microbatches, remat, compression,
                          hints=hints, grad_accum=grad_accum)
-    rep = NamedSharding(mesh, P())
     jitted = jax.jit(
-        fn,
+        ops.with_kernel_mesh(fn, mesh),
         in_shardings=(ps, os_, batch_sh),
         out_shardings=(ps, os_, None),
         donate_argnums=(0, 1),
@@ -287,7 +286,11 @@ def jit_grad_step(
                 loss, _, grads = grad_step(params, batch)
             return loss, grads
 
-    jitted = jax.jit(fn, in_shardings=(ps, batch_sh), out_shardings=(None, ps))
+    jitted = jax.jit(
+        ops.with_kernel_mesh(fn, mesh),
+        in_shardings=(ps, batch_sh),
+        out_shardings=(None, ps),
+    )
     return jitted, (ps, batch_sh)
 
 
@@ -336,7 +339,7 @@ def jit_prefill_step(
     batch_sh = {"tokens": bs}
     if cfg.family == "encdec":
         batch_sh["frames"] = bs
-    fn = make_prefill_step(cfg, max_seq=seq_len, hints=hints)
+    fn = ops.with_kernel_mesh(make_prefill_step(cfg, max_seq=seq_len, hints=hints), mesh)
     return jax.jit(fn, in_shardings=(ps, batch_sh)), (ps, batch_sh)
 
 

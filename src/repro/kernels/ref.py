@@ -149,25 +149,25 @@ def pack_rows_ref(src: jax.Array, row_starts: jax.Array, block_rows: int) -> jax
     """Gather ``len(row_starts)`` blocks of ``block_rows`` contiguous rows of
     ``src`` into a dense output (the paper's staging-buffer assemble loop).
 
-    src: (R, C); row_starts: (nb,) int32; out: (nb*block_rows, C).
+    src: (R, *tail); row_starts: (nb,) int32; out: (nb*block_rows, *tail).
     """
     nb = row_starts.shape[0]
 
     def take(start):
         return jax.lax.dynamic_slice_in_dim(src, start, block_rows, axis=0)
 
-    blocks = jax.vmap(take)(row_starts)  # (nb, block_rows, C)
-    return blocks.reshape(nb * block_rows, src.shape[1])
+    blocks = jax.vmap(take)(row_starts)  # (nb, block_rows, *tail)
+    return blocks.reshape((nb * block_rows,) + src.shape[1:])
 
 
 def unpack_rows_ref(
     buf: jax.Array, row_starts: jax.Array, block_rows: int, out_rows: int
 ) -> jax.Array:
-    """Inverse of pack_rows: scatter buffer blocks into a (out_rows, C) zero
-    array at the given row offsets."""
+    """Inverse of pack_rows: scatter buffer blocks into a (out_rows, *tail)
+    zero array at the given row offsets."""
     nb = row_starts.shape[0]
-    out = jnp.zeros((out_rows, buf.shape[1]), buf.dtype)
-    blocks = buf.reshape(nb, block_rows, buf.shape[1])
+    out = jnp.zeros((out_rows,) + buf.shape[1:], buf.dtype)
+    blocks = buf.reshape((nb, block_rows) + buf.shape[1:])
 
     def body(i, acc):
         return jax.lax.dynamic_update_slice_in_dim(
@@ -191,7 +191,7 @@ def relayout_rows_ref(
     def take(start):
         return jax.lax.dynamic_slice_in_dim(src, start, block_rows, axis=0)
 
-    blocks = jax.vmap(take)(row_starts)  # (nb, block_rows, C)
+    blocks = jax.vmap(take)(row_starts)  # (nb, block_rows, *tail)
 
     def body(i, acc):
         return jax.lax.dynamic_update_slice_in_dim(
@@ -273,7 +273,7 @@ def scatter_rows_ref(
     Pallas kernel's sequential grid.
     """
     nb = row_starts.shape[0]
-    blocks = buf.reshape(nb, block_rows, buf.shape[1])
+    blocks = buf.reshape((nb, block_rows) + buf.shape[1:])
 
     def body(i, acc):
         return jax.lax.dynamic_update_slice_in_dim(

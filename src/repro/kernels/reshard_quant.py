@@ -40,6 +40,9 @@ Oracles: :func:`repro.kernels.ref.pack_quant_rows_ref` /
 
 from __future__ import annotations
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -63,24 +66,31 @@ def wire_itemsize(fmt: str) -> int:
     return jnp.dtype(WIRE_QDTYPE[fmt]).itemsize
 
 
-def _quantize_tile(x: jax.Array, fmt: str) -> tuple[jax.Array, jax.Array]:
-    """Quantize one tile (any shape) → (q, scale ()-float32). Shared by the
-    kernel bodies and the jnp oracle so both paths are the same arithmetic.
-
-    The scale is ``absmax * (1/qmax)`` with the reciprocal folded to a
-    float32 constant, NOT ``absmax / qmax``: XLA strength-reduces division
-    by a constant to a reciprocal multiply only in some fusion contexts, so
-    the divide form computes 1-ULP-different scales between the Pallas
-    interpreter and the jnp oracle. Multiply form is bitwise-stable."""
-    xf = x.astype(jnp.float32)
-    qmax = WIRE_QMAX[fmt]
-    scale = jnp.maximum(jnp.max(jnp.abs(xf)), QUANT_EPS) * jnp.float32(1.0 / qmax)
+def _quantize(xf: jax.Array, scale: jax.Array, fmt: str) -> jax.Array:
+    """``xf / scale`` in the wire format (``scale`` broadcasts against
+    ``xf``). Shared by the kernel bodies, the per-tensor quantizer and the
+    oracle so every path is the same arithmetic."""
     y = xf / scale
     if fmt == "int8":
-        q = jnp.clip(jnp.round(y), -qmax, qmax).astype(jnp.int8)
-    else:
-        q = y.astype(jnp.float8_e4m3fn)
-    return q, scale
+        qmax = WIRE_QMAX[fmt]
+        return jnp.clip(jnp.round(y), -qmax, qmax).astype(jnp.int8)
+    return y.astype(jnp.float8_e4m3fn)
+
+
+def _tile_scale(absmax: jax.Array, fmt: str) -> jax.Array:
+    """Per-tile scale ``absmax * (1/qmax)`` with the reciprocal folded to a
+    float32 constant, NOT ``absmax / qmax``: XLA strength-reduces division
+    by a constant to a reciprocal multiply only in some fusion contexts, so
+    the divide form computes 1-ULP-different scales between compilation
+    contexts. Multiply form is bitwise-stable."""
+    return jnp.maximum(absmax, QUANT_EPS) * jnp.float32(1.0 / WIRE_QMAX[fmt])
+
+
+def _quantize_tile(x: jax.Array, fmt: str) -> tuple[jax.Array, jax.Array]:
+    """Quantize one tile (any shape) → (q, scale ()-float32)."""
+    xf = x.astype(jnp.float32)
+    scale = _tile_scale(jnp.max(jnp.abs(xf)), fmt)
+    return _quantize(xf, scale, fmt), scale
 
 
 def _dequantize_tile(q: jax.Array, scale: jax.Array, out_dtype) -> jax.Array:
@@ -88,18 +98,60 @@ def _dequantize_tile(q: jax.Array, scale: jax.Array, out_dtype) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernels
+# Pallas kernels (rank-2 ``(R, C)`` leaves; rows staged as in reshard_pack)
 # ---------------------------------------------------------------------------
 
 
-def _make_quant_kernel(fmt: str):
-    def kernel(starts_ref, src_ref, q_ref, scale_ref):
-        del starts_ref  # consumed by the index maps
-        q, scale = _quantize_tile(src_ref[...], fmt)
-        q_ref[...] = q
-        scale_ref[0, 0] = scale
+def quant_group(block_rows: int) -> int:
+    """Rows per grid step: whole 8-bit payload tiles (32 rows) holding a
+    multiple of 8 quantization tiles, so the (tiles, 1) sidecar block is
+    8-row aligned."""
+    return math.lcm(32, 8 * block_rows)
 
-    return kernel
+
+def quant_vmem_bytes(C: int, block_rows: int) -> int:
+    """VMEM one grid step of the quant kernels stages for a width-C row."""
+    g = quant_group(block_rows)
+    return g * 8 * C * 4 + g * C * 4 + 2 * g * C
+
+
+def _pack_quant_kernel(starts_ref, src, q_ref, s_ref, scr, rows, sem, *,
+                       n, block_rows, t, fmt):
+    """One step = ``G`` payload rows: DMA each row's aligned source tile
+    into VMEM, collect the rows, then quantize the whole block at once with
+    one scale per ``block_rows``-row tile."""
+    G = quant_group(block_rows)
+    g = pl.program_id(0)
+    pending = []
+    for j in range(G):
+        k = jnp.minimum(g * G + j, n - 1)  # rows past n are masked out
+        r = starts_ref[k // block_rows] + k % block_rows
+        base = pl.multiple_of((r // t) * t, t) if t == 8 else 0
+        cp = pltpu.make_async_copy(src.at[pl.ds(base, t)], scr.at[j], sem.at[j])
+        cp.start()
+        pending.append((cp, r - base))
+    for j, (cp, off) in enumerate(pending):
+        cp.wait()
+        rows[pl.ds(j, 1), :] = scr[j, pl.ds(off, 1), :].astype(jnp.float32)
+    x = rows[...]
+    absmax = jnp.max(jnp.abs(x), axis=1, keepdims=True)  # (G, 1)
+    if block_rows == 1:
+        scale = _tile_scale(absmax, fmt)
+        s_ref[...] = scale
+    else:
+        ntiles = G // block_rows
+        row_tile = jax.lax.broadcasted_iota(jnp.int32, (G, 1), 0) // block_rows
+        tile_id = jax.lax.broadcasted_iota(jnp.int32, (ntiles, 1), 0)
+        scale = jnp.zeros((G, 1), jnp.float32)
+        scales = jnp.zeros((ntiles, 1), jnp.float32)
+        for m in range(ntiles):
+            s_m = _tile_scale(
+                jnp.max(absmax[m * block_rows : (m + 1) * block_rows]), fmt
+            )
+            scale = jnp.where(row_tile == m, s_m, scale)
+            scales = jnp.where(tile_id == m, s_m, scales)
+        s_ref[...] = scales
+    q_ref[...] = _quantize(x, scale, fmt)
 
 
 def pack_quant_rows_pallas(
@@ -111,46 +163,72 @@ def pack_quant_rows_pallas(
 ) -> tuple[jax.Array, jax.Array]:
     """Gather + quantize nb row-blocks: ((nb*block_rows, C) q, (nb, 1) f32).
 
-    One grid step per tile: the block is gathered through the scalar-
-    prefetched offset table exactly like ``pack_rows_pallas``, its absmax
-    reduced in-register, and the quantized payload plus sidecar scale
-    written in the same pass — no second HBM round trip over the staged
-    bytes to compute scales.
+    The rows are gathered through the scalar-prefetched offset table into
+    VMEM, their absmax reduced in-register, and the quantized payload plus
+    sidecar scales written in the same pass — no second HBM round trip over
+    the staged bytes to compute scales.
     """
     nb = row_starts.shape[0]
-    C = src.shape[1]
-
+    n = nb * block_rows
+    R, C = src.shape
+    G = quant_group(block_rows)
+    t = 8 if R % 8 == 0 else R
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec(
-                (block_rows, C),
-                lambda i, starts: (starts[i] // block_rows, 0),
-            ),
-        ],
+        grid=(pl.cdiv(n, G),),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=[
-            pl.BlockSpec((block_rows, C), lambda i, starts: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, starts: (i, 0)),
+            pl.BlockSpec((G, C), lambda g, s: (g, 0)),
+            pl.BlockSpec((G // block_rows, 1), lambda g, s: (g, 0)),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((G, t, C), src.dtype),
+            pltpu.VMEM((G, C), jnp.float32),
+            pltpu.SemaphoreType.DMA((G,)),
         ],
     )
     return pl.pallas_call(
-        _make_quant_kernel(fmt),
+        functools.partial(
+            _pack_quant_kernel, n=n, block_rows=block_rows, t=t, fmt=fmt
+        ),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((nb * block_rows, C), WIRE_QDTYPE[fmt]),
+            jax.ShapeDtypeStruct((n, C), WIRE_QDTYPE[fmt]),
             jax.ShapeDtypeStruct((nb, 1), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(row_starts, src)
 
 
-def _make_dequant_scatter_kernel(out_dtype):
-    def kernel(starts_ref, buf_ref, scale_ref, dst_ref, o_ref):
-        del starts_ref, dst_ref  # starts: index maps; dst: aliased output
-        o_ref[...] = _dequantize_tile(buf_ref[...], scale_ref[0, 0], out_dtype)
+def _dequant_scatter_kernel(starts_ref, scales_ref, buf_ref, dst, out, deq, tile,
+                            sem, *, n, block_rows, t):
+    """One step = ``G`` payload rows: dequantize the block, then overwrite
+    each row into the aliased destination through its VMEM tile, one row at
+    a time in table order (duplicate starts last-wins)."""
+    del dst  # aliased into ``out``
+    G = quant_group(block_rows)
+    g = pl.program_id(0)
+    deq[...] = buf_ref[...].astype(jnp.float32)
+    sub = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 0)
+    for j in range(G):
+        k = g * G + j
 
-    return kernel
+        @pl.when(k < n)
+        def _():
+            r = starts_ref[k // block_rows] + k % block_rows
+            base = pl.multiple_of((r // t) * t, t) if t == 8 else 0
+            window = out.at[pl.ds(base, t)]
+            rd = pltpu.make_async_copy(window, tile, sem)
+            rd.start()
+            row = (deq[pl.ds(j, 1), :] * scales_ref[k // block_rows]).astype(
+                tile.dtype
+            )
+            rd.wait()
+            tile[...] = jnp.where(sub == r - base, row, tile[...])
+            wr = pltpu.make_async_copy(tile, window, sem)
+            wr.start()
+            wr.wait()
 
 
 def dequant_scatter_rows_pallas(
@@ -167,33 +245,38 @@ def dequant_scatter_rows_pallas(
     aliased-destination overwrite semantics (untouched rows keep their
     bytes, duplicate starts last-wins), with the per-tile dequant fused in
     front of the store instead of materializing a dequantized staging
-    buffer first.
+    buffer first. The sidecar rides in SMEM next to the offset table.
     """
     nb = row_starts.shape[0]
-    C = dst.shape[1]
-
+    n = nb * block_rows
+    R, C = dst.shape
+    G = quant_group(block_rows)
+    t = 8 if R % 8 == 0 else R
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nb,),
+        num_scalar_prefetch=2,
+        grid=(pl.cdiv(n, G),),
         in_specs=[
-            pl.BlockSpec((block_rows, C), lambda i, starts: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, starts: (i, 0)),
-            pl.BlockSpec(
-                (block_rows, C), lambda i, starts: (starts[i] // block_rows, 0)
-            ),
+            pl.BlockSpec((G, C), lambda g, s, sc: (g, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec(
-            (block_rows, C), lambda i, starts: (starts[i] // block_rows, 0)
-        ),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[
+            pltpu.VMEM((G, C), jnp.float32),
+            pltpu.VMEM((t, C), dst.dtype),
+            pltpu.SemaphoreType.DMA(()),
+        ],
     )
     return pl.pallas_call(
-        _make_dequant_scatter_kernel(dst.dtype),
+        functools.partial(
+            _dequant_scatter_kernel, n=n, block_rows=block_rows, t=t
+        ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(dst.shape, dst.dtype),
-        # flattened input index 3 (starts, buf, scales, dst) -> output 0
+        # flattened input index 3 (starts, scales, buf, dst) -> output 0
         input_output_aliases={3: 0},
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(row_starts, buf, scales, dst)
+    )(row_starts, scales.reshape(nb), buf, dst)
 
 
 # ---------------------------------------------------------------------------
@@ -205,11 +288,9 @@ def quantize_int8(g: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Symmetric per-tensor int8 quantization: (q int8, scale ()-f32).
 
     The whole-tensor special case of the wire format's per-tile quantizer
-    (one tile = the tensor); kept as the gradient-compression entry point
-    and the scalar oracle for the kernel tests.
+    (one tile = the tensor); kept as the gradient-compression entry point.
     """
-    q, scale = _quantize_tile(g, "int8")
-    return q, scale
+    return _quantize_tile(g, "int8")
 
 
 def dequantize_int8(q: jax.Array, scale: jax.Array) -> jax.Array:

@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import compiler_params as _compiler_params
-
 
 def _rmsnorm_kernel(x_ref, scale_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)  # (rows, d)
@@ -56,7 +54,7 @@ def rmsnorm_pallas(
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
-        compiler_params=_compiler_params(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x2, scale)
     if pad:

@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"  # host-device emulation; never claims a chip
 
 """Multi-pod dry-run driver.
 
@@ -7,8 +8,9 @@ Lowers + compiles every (architecture × input-shape) cell against the
 production mesh — 16×16 single-pod and 2×16×16 multi-pod — and records
 memory analysis, cost analysis and collective bytes for the roofline table.
 
-MUST be run as its own process (the XLA_FLAGS line above precedes every
-other import because jax locks the device count at first init):
+MUST be run as its own process (the XLA_FLAGS / JAX_PLATFORMS lines above
+precede every other import because jax locks the devices at first init;
+``--sweep`` children inherit both, so none of them reaches for a chip):
 
     PYTHONPATH=src python -m repro.launch.dryrun --arch mixtral-8x7b --shape train_4k
     PYTHONPATH=src python -m repro.launch.dryrun --sweep --out results/dryrun

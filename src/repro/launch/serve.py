@@ -25,7 +25,9 @@ def main() -> None:
 
     from repro.configs import get_config
     from repro.serve.driver import serve_once
+    from repro.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -62,7 +62,9 @@ def main() -> None:
     from repro.configs.base import ParallelConfig
     from repro.core.controller import LiveRController
     from repro.optim import AdamWConfig
+    from repro.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

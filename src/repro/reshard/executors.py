@@ -120,6 +120,8 @@ _SCATTER_CACHE: dict = {}
 _RELAYOUT_CACHE: dict = {}
 _RELAYOUT_ND_CACHE: dict = {}
 _DEQ_SCATTER_CACHE: dict = {}
+_PACK_CACHE: dict = {}
+_PACKQ_CACHE: dict = {}
 _JIT_CACHE_MAX = 64
 
 
@@ -149,7 +151,7 @@ def _await_staged(buf) -> float:
 
 def _jit_helpers():
     """Module-level jitted copy helpers (cached across executor instances)."""
-    global _DUS0, _DUS_ND, _PACK2D, _PACKQ2D
+    global _DUS0, _DUS_ND
     if "_DUS0" in globals():
         return
     import jax
@@ -169,23 +171,70 @@ def _jit_helpers():
         donate_argnums=(0,),
     )
 
-    def _pack2d(leaf, starts):
-        from repro.kernels import ops
 
-        return ops.pack_rows(leaf.reshape(leaf.shape[0], -1), starts, 1)
+def _rows(x):
+    """Rows on dim 0: a 1-D leaf moves as (R, 1) rows."""
+    return x.reshape(x.shape[0], 1) if x.ndim == 1 else x
 
-    # collapse-to-2D + row gather as one compiled program on the source mesh
-    # (caches per (leaf shape, starts length) family)
-    _PACK2D = jax.jit(_pack2d)
 
-    def _packq2d(leaf, starts, fmt):
-        from repro.kernels import ops
+def _flat_sharding(sharding, ndim: int):
+    """Sharding descriptor of a rank-``ndim`` leaf's (R, C) view: dim 0 as
+    the leaf's, every tail mesh axis merged onto the row. The kernels only
+    ask whether rows are split or whole, which this preserves."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
-        return ops.pack_quant_rows(leaf.reshape(leaf.shape[0], -1), starts, 1, fmt)
+    if not isinstance(sharding, NamedSharding):
+        return sharding
+    spec = tuple(sharding.spec) + (None,) * (ndim - len(sharding.spec))
+    tail = tuple(
+        a for ax in spec[1:] if ax is not None
+        for a in ((ax,) if isinstance(ax, str) else ax)
+    )
+    return NamedSharding(sharding.mesh, P(spec[0] if spec else None, tail or None))
 
-    # compressed-wire pack: gather + per-row quantize in one program on the
-    # source mesh, returning (int8/fp8 payload, float32 sidecar scales)
-    _PACKQ2D = jax.jit(_packq2d, static_argnums=(2,))
+
+def _rows_sharding(sharding, ndim: int):
+    """The sharding of ``_rows`` of a rank-``ndim`` leaf."""
+    return sharding if ndim > 1 else _flat_sharding(sharding, 1)
+
+
+def _pack_fn(sharding):
+    """Jitted row gather into a staging buffer on the SOURCE mesh, run per
+    device (the buffer keeps the leaf's tail layout)."""
+    fn = _PACK_CACHE.get(sharding)
+    if fn is None:
+        import jax
+
+        def f(leaf, starts):
+            from repro.kernels import ops
+
+            return ops.pack_rows(
+                _rows(leaf), starts, 1, sharding=_rows_sharding(sharding, leaf.ndim)
+            )
+
+        fn = _cache_put(_PACK_CACHE, sharding, jax.jit(f))
+    return fn
+
+
+def _packq_fn(sharding, fmt: str):
+    """Jitted compressed-wire pack on the source mesh: gather + per-row
+    quantize of the leaf's (R, C) view, returning (int8/fp8 payload,
+    float32 sidecar scales)."""
+    key = (sharding, fmt)
+    fn = _PACKQ_CACHE.get(key)
+    if fn is None:
+        import jax
+
+        def f(leaf, starts):
+            from repro.kernels import ops
+
+            return ops.pack_quant_rows(
+                leaf.reshape(leaf.shape[0], -1), starts, 1, fmt,
+                sharding=_flat_sharding(sharding, leaf.ndim),
+            )
+
+        fn = _cache_put(_PACKQ_CACHE, key, jax.jit(f))
+    return fn
 
 
 def _zeros_fn(shape: tuple, dtype: str, sharding):
@@ -207,10 +256,9 @@ def _zeros_fn(shape: tuple, dtype: str, sharding):
 
 
 def _scatter_fn(sharding):
-    """Jitted fused overwrite-scatter: collapse the carry to 2-D, scatter
-    the packed row buffer at the given offsets, restore the carry shape.
-    The carry is donated and the output pinned to the destination sharding
-    (reshape round-trips must not let GSPMD re-decide the layout).
+    """Jitted fused overwrite-scatter of the packed row buffer into the
+    carry at the given offsets, run per device on the destination mesh.
+    The carry is donated and the output pinned to the destination sharding.
     jax.jit caches traces per (carry, buf, starts) shape family underneath
     the per-sharding entry."""
     fn = _SCATTER_CACHE.get(sharding)
@@ -220,9 +268,11 @@ def _scatter_fn(sharding):
         def f(carry, buf, starts):
             from repro.kernels import ops
 
-            c2 = carry.reshape(carry.shape[0], -1)
-            c2 = ops.scatter_rows(c2, buf, starts, 1)
-            return c2.reshape(carry.shape)
+            out = ops.scatter_rows(
+                _rows(carry), buf, starts, 1,
+                sharding=_rows_sharding(sharding, carry.ndim),
+            )
+            return out.reshape(carry.shape)
 
         fn = _cache_put(
             _SCATTER_CACHE,
@@ -247,7 +297,10 @@ def _dequant_scatter_fn(sharding):
             from repro.kernels import ops
 
             c2 = carry.reshape(carry.shape[0], -1)
-            c2 = ops.dequant_scatter_rows(c2, buf, scales, starts, 1)
+            c2 = ops.dequant_scatter_rows(
+                c2, buf, scales, starts, 1,
+                sharding=_flat_sharding(sharding, carry.ndim),
+            )
             return c2.reshape(carry.shape)
 
         fn = _cache_put(
@@ -272,10 +325,11 @@ def _relayout_fn(sharding):
         def f(carry, leaf, starts):
             from repro.kernels import ops
 
-            c2 = carry.reshape(carry.shape[0], -1)
-            l2 = leaf.reshape(leaf.shape[0], -1)
-            c2 = ops.relayout_rows(c2, l2, starts, 1)
-            return c2.reshape(carry.shape)
+            out = ops.relayout_rows(
+                _rows(carry), _rows(leaf), starts, 1,
+                sharding=_rows_sharding(sharding, carry.ndim),
+            )
+            return out.reshape(carry.shape)
 
         fn = _cache_put(
             _RELAYOUT_CACHE,
@@ -394,8 +448,9 @@ class LiveExecutor:
         # would poison the destination (these are scalars; skip the free)
         self._no_release: set[str] = set()
         # last-resort staging layout: replicated on the target mesh (used
-        # for the packed 2-D buffer whose collapsed dims defeat the spec);
-        # sliced chunks stage in the target's own non-dim0 layout instead
+        # for the quantized payload, whose collapsed (R, C) rows defeat the
+        # spec, and for offset tables); packed buffers and sliced chunks
+        # stage in the target's own non-dim0 layout instead
         any_sh = next(iter(target_shardings.values()))
         self._replicated_sh = NamedSharding(any_sh.mesh, P())
         self._jnp = jnp
@@ -683,7 +738,7 @@ class LiveExecutor:
                 # carry. Used for contiguous runs too — the wire transfer,
                 # not the dispatch count, is what compression shrinks.
                 starts = jnp.asarray(batch, jnp.int32)
-                qbuf, scales = _PACKQ2D(leaf, starts, fmt)
+                qbuf, scales = _packq_fn(leaf.sharding, fmt)(leaf, starts)
                 qbuf = jax.device_put(qbuf, self._replicated_sh)
                 scales = jax.device_put(scales, self._replicated_sh)
                 starts_dev = jax.device_put(starts, self._replicated_sh)
@@ -708,9 +763,8 @@ class LiveExecutor:
                 # but is NOT idempotent: re-streaming a dirty layer would
                 # compound onto the stale pre-copied value.)
                 starts = jnp.asarray(batch, jnp.int32)
-                buf = jax.device_put(
-                    _PACK2D(leaf, starts), self._replicated_sh
-                )
+                buf = _pack_fn(leaf.sharding)(leaf, starts)
+                buf = jax.device_put(buf, self._stage_sharding(name, buf.shape))
                 starts_dev = jax.device_put(starts, self._replicated_sh)
                 carry = _scatter_fn(self.target_shardings[name])(
                     carry, buf, starts_dev
@@ -719,14 +773,9 @@ class LiveExecutor:
             else:
                 # legacy baseline (bench_dataplane's "per-run DUS" path):
                 # pack once, then per-run slice + dynamic-update-slice
-                from repro.kernels import ops
-
-                R = spec.shape[0]
-                C = int(math.prod(tail)) if tail else 1
-                src2d = leaf.reshape(R, C)
                 starts = jnp.asarray(batch, jnp.int32)
-                buf = ops.pack_rows(src2d, starts, 1)
-                buf = jax.device_put(buf, self._replicated_sh)
+                buf = _pack_fn(leaf.sharding)(leaf, starts)
+                buf = jax.device_put(buf, self._stage_sharding(name, buf.shape))
                 self._stage(buf)
                 off = 0
                 for lo, hi in runs:

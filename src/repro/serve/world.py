@@ -23,6 +23,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ParallelConfig
 from repro.core.shadow import WorldHandle
+from repro.kernels import ops
 from repro.serve.cache_view import serve_state_specs, target_shardings_by_name
 from repro.utils.pytree import tree_from_paths, tree_paths
 
@@ -90,7 +91,10 @@ def build_serve_world(
             out_shardings=(rep, csh),
         )
     prefill_fn = jax.jit(
-        lambda p, b: M.prefill(cfg, p, b, cache_dtype=cache_dtype, max_seq=max_seq),
+        ops.with_kernel_mesh(
+            lambda p, b: M.prefill(cfg, p, b, cache_dtype=cache_dtype, max_seq=max_seq),
+            mesh,
+        ),
         in_shardings=(psh, rep),
         out_shardings=(rep, csh, xsh),
     )

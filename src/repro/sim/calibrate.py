@@ -35,7 +35,9 @@ def measure(force: bool = False) -> dict:
     # jax import + backend init in a fresh process
     t0 = time.perf_counter()
     subprocess.run(
-        [sys.executable, "-c", "import jax; jax.devices()"], check=True
+        [sys.executable, "-c", "import jax; jax.devices()"],
+        check=True,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},  # never contend for a chip
     )
     out["jax_init_s"] = time.perf_counter() - t0
 

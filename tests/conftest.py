@@ -12,7 +12,6 @@ import os
 import subprocess
 import sys
 import textwrap
-import types
 
 import pytest
 
@@ -36,127 +35,11 @@ SRC = os.path.join(REPO, "src")
 RESHAPE_PARITY_TOL = 1e-2
 
 
-# ---------------------------------------------------------------------------
-# hypothesis fallback: this container cannot pip-install hypothesis, and a
-# bare `from hypothesis import given` breaks collection of three modules.
-# When the real package is absent we register a minimal deterministic stand-in
-# that degrades each @given property to a seeded sample sweep (same API
-# surface the tests use: given/settings/strategies.{integers,floats,
-# sampled_from,builds,lists,data}). With hypothesis installed this block
-# is inert.
-# ---------------------------------------------------------------------------
-
-try:  # pragma: no cover - exercised implicitly by collection
-    import hypothesis  # noqa: F401
-except ImportError:
-
-    class _Strategy:
-        def sample(self, rng):
-            raise NotImplementedError
-
-    class _SampledFrom(_Strategy):
-        def __init__(self, choices):
-            self.choices = list(choices)
-
-        def sample(self, rng):
-            return self.choices[int(rng.integers(0, len(self.choices)))]
-
-    class _Integers(_Strategy):
-        def __init__(self, lo, hi):
-            self.lo, self.hi = lo, hi
-
-        def sample(self, rng):
-            return int(rng.integers(self.lo, self.hi + 1))
-
-    class _Floats(_Strategy):
-        def __init__(self, lo, hi):
-            self.lo, self.hi = lo, hi
-
-        def sample(self, rng):
-            return float(rng.uniform(self.lo, self.hi))
-
-    class _Builds(_Strategy):
-        def __init__(self, target, **kw):
-            self.target, self.kw = target, kw
-
-        def sample(self, rng):
-            return self.target(**{k: s.sample(rng) for k, s in self.kw.items()})
-
-    class _Lists(_Strategy):
-        def __init__(self, elements, min_size=0, max_size=10, unique=False):
-            self.elements = elements
-            self.min_size, self.max_size, self.unique = min_size, max_size, unique
-
-        def sample(self, rng):
-            n = int(rng.integers(self.min_size, self.max_size + 1))
-            out: list = []
-            attempts = 0
-            while len(out) < n and attempts < 1000:
-                v = self.elements.sample(rng)
-                attempts += 1
-                if self.unique and v in out:
-                    continue
-                out.append(v)
-            assert len(out) == n, "fallback lists(): could not draw enough uniques"
-            return out
-
-    class _DataObject:
-        def __init__(self, rng):
-            self._rng = rng
-
-        def draw(self, strategy, label=None):
-            return strategy.sample(self._rng)
-
-    class _Data(_Strategy):
-        def sample(self, rng):
-            return _DataObject(rng)
-
-    def _settings(max_examples=20, deadline=None, **_kw):
-        def deco(fn):
-            fn._fallback_max_examples = max_examples
-            return fn
-
-        return deco
-
-    def _given(**strategies):
-        def deco(fn):
-            # NB: no functools.wraps — pytest would follow __wrapped__ and
-            # treat the drawn parameters as fixtures.
-            def wrapper(*args, **kw):
-                import numpy as _np
-
-                n = getattr(wrapper, "_fallback_max_examples", 20)
-                rng = _np.random.default_rng(0)
-                for _ in range(n):
-                    drawn = {k: s.sample(rng) for k, s in strategies.items()}
-                    fn(*args, **kw, **drawn)
-
-            wrapper.__name__ = fn.__name__
-            wrapper.__doc__ = fn.__doc__
-            wrapper.__module__ = fn.__module__
-            return wrapper
-
-        return deco
-
-    _st = types.ModuleType("hypothesis.strategies")
-    _st.sampled_from = _SampledFrom
-    _st.integers = _Integers
-    _st.floats = _Floats
-    _st.builds = _Builds
-    _st.lists = _Lists
-    _st.data = _Data
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.strategies = _st
-    _hyp.__is_fallback__ = True
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st
-
-
 def run_with_devices(code: str, n_devices: int = 8, timeout: int = 900) -> str:
-    """Run a python snippet in a subprocess with N host platform devices."""
+    """Run a python snippet in a subprocess with N host platform devices
+    (held to the CPU: a child never contends for an accelerator)."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run(

@@ -292,3 +292,65 @@ def test_pack_then_scatter_roundtrip():
     for r in np.asarray(rows):
         exp[r] = np.asarray(src)[r]
     np.testing.assert_array_equal(np.asarray(out), exp)
+
+
+# ---------------------------------------------------------------------------
+# flash attention VJP, rank-3 row kernels, counted fallbacks
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("window", [0, 64])
+def test_flash_attention_vjp_matches_reference_grad(window):
+    """jax.grad through the kernel (custom_vjp) == jax.grad of the oracle."""
+    q = _rand((1, 256, 4, 32))
+    k = _rand((1, 256, 2, 32))
+    v = _rand((1, 256, 2, 32))
+    w = _rand((1, 256, 4, 32))
+
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v) * w).sum()
+
+    kern = loss(
+        lambda q, k, v: flash_attention_pallas(q, k, v, window=window, interpret=True)
+    )
+    orac = loss(lambda q, k, v: ref.flash_attention_ref(q, k, v, window=window))
+    got = jax.grad(kern, argnums=(0, 1, 2))(q, k, v)
+    exp = jax.grad(orac, argnums=(0, 1, 2))(q, k, v)
+    for g, e in zip(got, exp):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(e), atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("kernel", ["pack", "scatter", "relayout"])
+def test_row_kernels_rank3_match_reference(kernel):
+    """Stacked-layer leaves (rows = whole (d, f) slabs) take the HBM->HBM
+    DMA path; bytes equal the oracle, duplicate starts last-wins."""
+    src = _rand((6, 16, 128))
+    dst = _rand((6, 16, 128))
+    starts = jnp.asarray([4, 0, 4, 2], jnp.int32)
+    if kernel == "pack":
+        got = pack_rows_pallas(src, starts, 1, interpret=True)
+        exp = ref.pack_rows_ref(src, starts, 1)
+    elif kernel == "scatter":
+        buf = _rand((4, 16, 128))
+        got = scatter_rows_pallas(dst, buf, starts, 1, interpret=True)
+        exp = ref.scatter_rows_ref(dst, buf, starts, 1)
+    else:
+        got = relayout_rows_pallas(dst, src, starts, 1, interpret=True)
+        exp = ref.relayout_rows_ref(dst, src, starts, 1)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(exp))
+
+
+def test_unfit_shapes_fall_back_counted(monkeypatch):
+    """Where the kernels are in use, a call they cannot take runs on the
+    reference and is counted — never silently."""
+    from repro.kernels import ops
+
+    monkeypatch.setenv("REPRO_FORCE_PALLAS_INTERPRET", "1")
+    ops.FALLBACKS.clear()
+    q = _rand((1, 96, 2, 32))
+    out = ops.flash_attention(q, q, q)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(ref.flash_attention_ref(q, q, q)), atol=1e-6
+    )
+    assert ops.FALLBACKS == {"flash_attention: seq 96/96 not a multiple of 128": 1}
+    ops.FALLBACKS.clear()
