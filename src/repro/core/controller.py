@@ -18,10 +18,9 @@ and ``escalate_commit`` (deadline-pressure stop-copy).
 
 from __future__ import annotations
 
-import os
+import logging
 import threading
 import time
-import traceback
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
@@ -54,6 +53,13 @@ from repro.data import SyntheticLM
 from repro.optim import AdamWConfig
 from repro.reshard import OverlapSession
 from repro.utils.pytree import tree_paths
+from repro.utils.spans import span
+
+_log = logging.getLogger(__name__)
+# a step slower than this many times the median of the last
+# SLOW_STEP_WINDOW steps logs its phase split (train_steps)
+SLOW_STEP_FACTOR = 2.0
+SLOW_STEP_WINDOW = 64
 
 
 @dataclass
@@ -288,12 +294,12 @@ class LiveRController:
         handle.timings["prepare_source"] = source
         handle.plan_bundle = None  # src-dependent: always replanned below
         if mode == "stream" and handle.update_fn is None:
-            t0 = time.perf_counter()
-            handle.update_fn = build_update_world_fn(
-                self.cfg, handle.mesh, handle.parallel, self.opt_cfg,
-                compression=self.compression,
-            )
-            handle.timings["update_compile_s"] = time.perf_counter() - t0
+            with span("liver/world/update_compile") as ph:
+                handle.update_fn = build_update_world_fn(
+                    self.cfg, handle.mesh, handle.parallel, self.opt_cfg,
+                    compression=self.compression,
+                )
+            handle.timings["update_compile_s"] = ph.seconds
         return handle
 
     def _discard_world(self, handle: WorldHandle) -> None:
@@ -357,20 +363,6 @@ class LiveRController:
         ).start()
         return True
 
-    @staticmethod
-    def _speculation_trace(msg: str) -> None:
-        """Append one line to the file named by REPRO_PREWARM_TRACE (unset:
-        no-op). Speculative threads swallow their failures by design — this
-        is the only way to see what the speculation layer actually did."""
-        path = os.environ.get("REPRO_PREWARM_TRACE")
-        if not path:
-            return
-        try:
-            with open(path, "a") as f:
-                f.write(f"{time.perf_counter():.3f} {msg}\n")
-        except OSError:
-            pass
-
     def _derived_named_shardings(self, parallel: ParallelConfig) -> Optional[dict]:
         """Named state shardings a world under ``parallel`` WILL carry,
         derived from mesh + rules alone — no build, no compile (~ms).
@@ -427,7 +419,6 @@ class LiveRController:
             return 0
         src_sh = self._derived_named_shardings(target)
         if src_sh is None:
-            self._speculation_trace(f"ahead: no derived shardings for {target}")
             return 0
         started = 0
         for tgt in needed:
@@ -482,10 +473,6 @@ class LiveRController:
         handle = self.world_pool.peek(self.pool_key(target))
         if handle is None or handle.released:
             return False
-        self._speculation_trace(
-            f"prewarm start {src_parallel.describe()}->{target.describe()} "
-            f"ahead={ahead}"
-        )
         self._prewarmed_pairs.add(pair)
         self._prewarm_pair = pair
         targets = self._named_target_shardings(handle)
@@ -567,19 +554,10 @@ class LiveRController:
                     live_reshard(
                         dummy_extras, extra_sh, staging_bytes=self.staging_bytes
                     )
-                self._speculation_trace(
-                    f"prewarm done {src_parallel.describe()}"
-                    f"->{target.describe()} ahead={ahead}"
-                )
             except BaseException as e:
                 # speculation must never take down training; the real
                 # transfer will compile (and surface errors) on its own
                 self.swallowed_errors.append(f"transfer prewarm: {e!r}")
-                self._speculation_trace(
-                    f"prewarm FAILED {src_parallel.describe()}"
-                    f"->{target.describe()} ahead={ahead}\n"
-                    + traceback.format_exc()
-                )
 
         # Non-daemon: a daemon thread killed inside an XLA compile at
         # interpreter exit aborts the process ("terminate called without
@@ -825,31 +803,61 @@ class LiveRController:
     def train_steps(self, n: int, collect: Optional[Callable] = None) -> list[float]:
         losses = []
         for _ in range(n):
-            t0 = time.perf_counter()
-            batch = self._batch()
-            if self._commit_armed:
-                # overlapped mode: this step runs split (grads on the old
-                # world overlapped with the dirty re-sync; optimizer update
-                # on the new world) and commits the switch at its end
-                metrics = self._split_step_commit(batch)
-            else:
-                self.params, self.opt_state, metrics = self.world.step_fn(
-                    self.params, self.opt_state, batch
-                )
-            jax.block_until_ready(metrics["loss"])
-            dt = time.perf_counter() - t0
-            self.iteration_times.append(dt)
-            self.ledger.record(t0, t0 + dt, "train", self.world.parallel.world_size)
-            losses.append(float(metrics["loss"]))
-            self.step += 1
-            if collect:
-                collect(self.step, metrics)
-            if self._ckpt and self.step % self.ckpt_interval == 0:
-                self._ckpt.save(self.step, {"params": self.params, "opt": self.opt_state})
-            if self.parity_every and self.step % self.parity_every == 0:
-                self._refresh_parity()
-            self._poll_boundary()
+            step = self.step
+            with span("liver/step", step=step) as whole:
+                with span("liver/batch", step=step) as feed:
+                    batch = self._batch()
+                with span("liver/dispatch", step=step) as dispatch:
+                    if self._commit_armed:
+                        # overlapped mode: this step runs split (grads on the
+                        # old world overlapped with the dirty re-sync;
+                        # optimizer update on the new world) and commits the
+                        # switch at its end
+                        metrics = self._split_step_commit(batch)
+                    else:
+                        self.params, self.opt_state, metrics = self.world.step_fn(
+                            self.params, self.opt_state, batch
+                        )
+                with span("liver/wait", step=step) as wait:
+                    jax.block_until_ready(metrics["loss"])
+                    t1 = time.perf_counter()
+                    losses.append(float(metrics["loss"]))
+                with span("liver/boundary", step=step) as boundary:
+                    t0 = feed.t0
+                    self.iteration_times.append(t1 - t0)
+                    self.ledger.record(t0, t1, "train", self.world.parallel.world_size)
+                    self.step += 1
+                    if collect:
+                        collect(self.step, metrics)
+                    if self._ckpt and self.step % self.ckpt_interval == 0:
+                        self._ckpt.save(
+                            self.step, {"params": self.params, "opt": self.opt_state}
+                        )
+                    if self.parity_every and self.step % self.parity_every == 0:
+                        self._refresh_parity()
+                    self._poll_boundary()
+            self._report_slow_step(whole, (feed, dispatch, wait, boundary))
         return losses
+
+    def _report_slow_step(self, whole: span, phases: tuple) -> None:
+        """Log the phase split of a step that took more than
+        ``SLOW_STEP_FACTOR`` times the median of the recent ones: a host
+        stall then names the phase it was in, with the profiler off."""
+        recent = self.iteration_times[-SLOW_STEP_WINDOW - 1 : -1]
+        if len(recent) < SLOW_STEP_WINDOW // 4:
+            return
+        # the minimum bounds the median from below: most steps stop here
+        # without a sort
+        if whole.seconds <= SLOW_STEP_FACTOR * min(recent):
+            return
+        median = sorted(recent)[len(recent) // 2]
+        if whole.seconds <= SLOW_STEP_FACTOR * median:
+            return
+        split = ", ".join(f"{p.name} {1e3 * p.seconds:.1f}" for p in phases)
+        _log.warning(
+            "step %d took %.1f ms against a median of %.1f ms: %s (ms)",
+            whole.ids["step"], 1e3 * whole.seconds, 1e3 * median, split,
+        )
 
     def _refresh_parity(self) -> None:
         """Idle-boundary XOR parity snapshot (spare-shard scheme, §15)."""
@@ -895,11 +903,10 @@ class LiveRController:
         # arm the split-step commit for the NEXT train step
         if self._session is None:
             self._start_overlap_session()
-        t0 = time.perf_counter()
-        named, _ = named_state_leaves(self.params, self.opt_state)
-        self._session.stream_next(named, self.step)
-        dt = time.perf_counter() - t0
-        self.ledger.record(t0, t0 + dt, "reshard_overlap",
+        with span("liver/stream_round", gen=self._builder.gen_id) as rnd:
+            named, _ = named_state_leaves(self.params, self.opt_state)
+            self._session.stream_next(named, self.step)
+        self.ledger.record(rnd.t0, rnd.t1, "reshard_overlap",
                            self.world.parallel.world_size)
         if not self._session.done_precopy:
             return
@@ -998,6 +1005,7 @@ class LiveRController:
             stream_k=stream_k,
             wire_policy=self.wire_policy,
             wire_bw_bytes_s=self.wire_bw_bytes_s,
+            gen_id=self._builder.gen_id,
         )
         self._pending_rec = ReconfigRecord(
             gen_id=self._builder.gen_id,
@@ -1082,60 +1090,62 @@ class LiveRController:
             warm_hit=bool(new_world.timings.get("warm_hit", False)),
             prepare_source=new_world.timings.get("prepare_source", "cold"),
         )
-        pause_start = time.perf_counter()
-        self.machine.begin_switch(gen_id)
+        with span("liver/commit", gen=gen_id) as pause:
+            self.machine.begin_switch(gen_id)
 
-        # 1. drain: all in-flight device work completes (1F1B boundary)
-        t0 = time.perf_counter()
-        jax.block_until_ready((self.params, self.opt_state))
-        rec.drain_s = time.perf_counter() - t0
+            # 1. drain: all in-flight device work completes (1F1B boundary)
+            with span("liver/commit/drain", gen=gen_id) as ph:
+                jax.block_until_ready((self.params, self.opt_state))
+            rec.drain_s = ph.seconds
 
-        # 2. streaming transfer: the plan executed on live arrays through
-        # the shared engine (same protocol code as the sim oracle)
-        t0 = time.perf_counter()
-        named, extras = named_state_leaves(self.params, self.opt_state)
-        _, op_staging = self._op_params()
-        moved, stats = live_reshard_planned(
-            self._session_specs,
-            plan,
-            named,
-            self._session_targets,
-            staging_bytes=op_staging,
-            wire_policy=self.wire_policy,
-            wire_bw_bytes_s=self.wire_bw_bytes_s,
-        )
-        new_extras, rep_x = live_reshard(
-            extras, self._extra_shardings(new_world),
-            staging_bytes=op_staging,
-        )
-        moved_state = rebuild_state(moved, self.params, self.opt_state, new_extras)
-        if self.commit_observer is not None:
-            self.commit_observer((self.params, self.opt_state), moved_state)
-        self.params, self.opt_state = moved_state
-        rec.transfer_s = time.perf_counter() - t0
-        rec.moved_bytes = (
-            stats.network_bytes + stats.local_bytes + rep_x.moved_bytes
-        )
-        rec.skipped_bytes = stats.resident_bytes
-        rec.resident_cells = stats.resident_cells
-        rec.wire_bytes = stats.wire_bytes
-        rec.logical_bytes = stats.logical_bytes
-        rec.executed_bytes = stats.executed_bytes + rep_x.moved_bytes
-        rec.stream_dispatch_s = stats.dispatch_seconds
-        rec.stream_drain_s = stats.drain_seconds
-        rec.generic_cells = stats.generic_cells
-        if self._operating_point is not None:
-            rec.operating_point = self._operating_point.to_dict()
+            # 2. streaming transfer: the plan executed on live arrays
+            # through the shared engine (same protocol code as the sim
+            # oracle)
+            with span("liver/commit/transfer", gen=gen_id) as ph:
+                named, extras = named_state_leaves(self.params, self.opt_state)
+                _, op_staging = self._op_params()
+                moved, stats = live_reshard_planned(
+                    self._session_specs,
+                    plan,
+                    named,
+                    self._session_targets,
+                    staging_bytes=op_staging,
+                    wire_policy=self.wire_policy,
+                    wire_bw_bytes_s=self.wire_bw_bytes_s,
+                )
+                new_extras, rep_x = live_reshard(
+                    extras, self._extra_shardings(new_world),
+                    staging_bytes=op_staging,
+                )
+                moved_state = rebuild_state(
+                    moved, self.params, self.opt_state, new_extras
+                )
+                if self.commit_observer is not None:
+                    self.commit_observer((self.params, self.opt_state), moved_state)
+                self.params, self.opt_state = moved_state
+            rec.transfer_s = ph.seconds
+            rec.moved_bytes = (
+                stats.network_bytes + stats.local_bytes + rep_x.moved_bytes
+            )
+            rec.skipped_bytes = stats.resident_bytes
+            rec.resident_cells = stats.resident_cells
+            rec.wire_bytes = stats.wire_bytes
+            rec.logical_bytes = stats.logical_bytes
+            rec.executed_bytes = stats.executed_bytes + rep_x.moved_bytes
+            rec.stream_dispatch_s = stats.dispatch_seconds
+            rec.stream_drain_s = stats.drain_seconds
+            rec.generic_cells = stats.generic_cells
+            if self._operating_point is not None:
+                rec.operating_point = self._operating_point.to_dict()
 
-        # 3. atomic switch: pointer swap of world references
-        t0 = time.perf_counter()
-        old = self.machine.commit_switch(gen_id)
-        rec.switch_s = time.perf_counter() - t0
-
-        rec.total_pause_s = time.perf_counter() - pause_start
+            # 3. atomic switch: pointer swap of world references
+            with span("liver/commit/switch", gen=gen_id) as ph:
+                old = self.machine.commit_switch(gen_id)
+            rec.switch_s = ph.seconds
+        rec.total_pause_s = pause.seconds
         self.ledger.record(
-            pause_start,
-            pause_start + rec.total_pause_s,
+            pause.t0,
+            pause.t1,
             "pause",
             max(self.world.parallel.world_size, new_world.parallel.world_size),
         )
@@ -1161,7 +1171,6 @@ class LiveRController:
 
         # dispatch the final gradient computation on the OLD world (params
         # are not donated: they are simultaneously the re-sync source)
-        t0 = time.perf_counter()
         loss, grads = self.world.grad_fn(self.params, batch)
 
         # overlapped with it: re-sync every dirty layer from this
@@ -1177,69 +1186,75 @@ class LiveRController:
             extras, self._extra_shardings(new_world),
             staging_bytes=op_staging,
         )
-        t1 = time.perf_counter()
-        jax.block_until_ready((loss, grads))
-        grad_tail_s = time.perf_counter() - t1  # residual wait past overlap
+        # residual wait past the overlap, outside the pause
+        with span("liver/commit/grad_tail", gen=gen_id) as grad_tail:
+            jax.block_until_ready((loss, grads))
 
         # ---- the commit pause: re-sync tail + grad reshard + update +
         # pointer swap. session.drain() is the ONLY blocking wait on the
         # streamed state (per-round barriers were retired with the async
         # data plane); it must land before update_fn may donate the
         # destination carries ----
-        pause_start = time.perf_counter()
-        self.machine.begin_switch(gen_id)
-        commit_drain_s = session.drain()
-        t0 = time.perf_counter()
-        p_specs = [s for s in self._session_specs if s.collection == "params"]
-        from repro.core.intersection import TransferPlan
+        with span("liver/commit", gen=gen_id) as pause:
+            self.machine.begin_switch(gen_id)
+            with span("liver/commit/drain", gen=gen_id) as commit_drain:
+                session.drain()
+            with span("liver/commit/transfer", gen=gen_id) as ph:
+                p_specs = [
+                    s for s in self._session_specs if s.collection == "params"
+                ]
+                from repro.core.intersection import TransferPlan
 
-        p_plan = TransferPlan(
-            tasks=[t for t in plan.tasks if t.collection == "params"],
-            cfg_src=plan.cfg_src,
-            cfg_dst=plan.cfg_dst,
-        )
-        g_named = {
-            f"params/{p}": leaf for p, leaf in tree_paths(grads).items()
-        }
-        g_targets = {
-            k: v for k, v in self._session_targets.items()
-            if k.startswith("params/")
-        }
-        g_moved, g_stats = live_reshard_planned(
-            p_specs, p_plan, g_named, g_targets,
-            staging_bytes=op_staging,
-            wire_policy=self.wire_policy,
-            wire_bw_bytes_s=self.wire_bw_bytes_s,
-        )
-        from repro.utils.pytree import tree_from_paths
+                p_plan = TransferPlan(
+                    tasks=[t for t in plan.tasks if t.collection == "params"],
+                    cfg_src=plan.cfg_src,
+                    cfg_dst=plan.cfg_dst,
+                )
+                g_named = {
+                    f"params/{p}": leaf for p, leaf in tree_paths(grads).items()
+                }
+                g_targets = {
+                    k: v for k, v in self._session_targets.items()
+                    if k.startswith("params/")
+                }
+                g_moved, g_stats = live_reshard_planned(
+                    p_specs, p_plan, g_named, g_targets,
+                    staging_bytes=op_staging,
+                    wire_policy=self.wire_policy,
+                    wire_bw_bytes_s=self.wire_bw_bytes_s,
+                )
+                from repro.utils.pytree import tree_from_paths
 
-        grads_new = tree_from_paths(
-            {p: g_moved[f"params/{p}"] for p in tree_paths(grads)}, grads
-        )
-        rec.transfer_s = time.perf_counter() - t0
+                grads_new = tree_from_paths(
+                    {p: g_moved[f"params/{p}"] for p in tree_paths(grads)}, grads
+                )
+            rec.transfer_s = ph.seconds
 
-        t0 = time.perf_counter()
-        params_new, opt_new = rebuild_state(
-            session.results(), self.params, self.opt_state, new_extras
-        )
-        if self.commit_observer is not None:
-            self.commit_observer((self.params, self.opt_state), (params_new, opt_new))
-        self.params, self.opt_state, om = new_world.update_fn(
-            grads_new, opt_new, params_new
-        )
-        jax.block_until_ready(self.params)
-        rec.update_s = time.perf_counter() - t0
+            with span("liver/commit/update", gen=gen_id) as ph:
+                params_new, opt_new = rebuild_state(
+                    session.results(), self.params, self.opt_state, new_extras
+                )
+                if self.commit_observer is not None:
+                    self.commit_observer(
+                        (self.params, self.opt_state), (params_new, opt_new)
+                    )
+                self.params, self.opt_state, om = new_world.update_fn(
+                    grads_new, opt_new, params_new
+                )
+                jax.block_until_ready(self.params)
+            rec.update_s = ph.seconds
 
-        t0 = time.perf_counter()
-        old = self.machine.commit_switch(gen_id)
-        rec.switch_s = time.perf_counter() - t0
-        rec.total_pause_s = time.perf_counter() - pause_start
+            with span("liver/commit/switch", gen=gen_id) as ph:
+                old = self.machine.commit_switch(gen_id)
+            rec.switch_s = ph.seconds
+        rec.total_pause_s = pause.seconds
+        commit_drain_s = commit_drain.seconds
 
         rep = session.report
         # drain_s = residual waits: grad tail outside the pause + re-sync
         # tail inside it (commit_drain_s appears here and on the drain-side
         # axis below, nowhere else — the phase columns stay additive)
-        rec.drain_s = grad_tail_s + commit_drain_s
+        rec.drain_s = grad_tail.seconds + commit_drain_s
         rec.precopy_s = rep.precopy_seconds
         rec.precopy_bytes = rep.precopy_bytes
         rec.resync_s = rep.resync_seconds
@@ -1262,7 +1277,7 @@ class LiveRController:
         rec.moved_bytes = rep.total_bytes + g_stats.network_bytes + g_stats.local_bytes
         rec.executed_bytes = session.stats.executed_bytes + g_stats.executed_bytes
         self.ledger.record(
-            pause_start, pause_start + rec.total_pause_s, "pause",
+            pause.t0, pause.t1, "pause",
             max(self.world.parallel.world_size, new_world.parallel.world_size),
         )
         self.records.append(rec)
@@ -1415,10 +1430,6 @@ class LiveRController:
             and self._prewarm_pair == (src_parallel, target)
         ):
             self._prewarm_thread.join(timeout=60.0)
-        self._speculation_trace(
-            f"recover {src_parallel.describe()}->{target.describe()} "
-            f"prewarmed={(src_parallel, target) in self._prewarmed_pairs}"
-        )
 
         # survivor-constrained plan (metadata only)
         t0 = time.perf_counter()

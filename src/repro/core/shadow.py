@@ -18,7 +18,6 @@ experiment is reproduced in benchmarks/bench_interference.py.
 from __future__ import annotations
 
 import threading
-import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
@@ -26,6 +25,7 @@ import jax
 from jax.sharding import Mesh
 
 from repro.configs.base import ModelConfig, ParallelConfig
+from repro.utils.spans import span
 
 
 @dataclass
@@ -107,11 +107,12 @@ class ShadowBuilder:
         return self
 
     def _run(self) -> None:
-        self.started_at = time.perf_counter()
         try:
-            handle = self._build_fn()
-            handle.gen_id = self.gen_id
-            handle.timings["prepare_total_s"] = time.perf_counter() - self.started_at
+            with span("liver/prepare", gen=self.gen_id) as prepare:
+                self.started_at = prepare.t0
+                handle = self._build_fn()
+                handle.gen_id = self.gen_id
+            handle.timings["prepare_total_s"] = prepare.seconds
             self._result = handle
         except BaseException as e:  # surfaced on result()
             self._error = e
@@ -233,9 +234,9 @@ def build_train_world(
     from repro.models.model import abstract_params
 
     timings: dict = {}
-    t0 = time.perf_counter()
-    mesh = make_elastic_mesh(parallel, devices=devices)
-    timings["mesh_s"] = time.perf_counter() - t0
+    with span("liver/world/mesh") as ph:
+        mesh = make_elastic_mesh(parallel, devices=devices)
+    timings["mesh_s"] = ph.seconds
 
     if parallel.pp > 1:
         from repro.distribution.pipeline import jit_pipeline_train_step
@@ -259,22 +260,22 @@ def build_train_world(
         aparams = abstract_params(cfg)
         aopt = _abstract_opt(cfg, aparams, compression)
         abatch = abstract_batch(cfg, global_batch, seq_len)
-        t0 = time.perf_counter()
-        lowered = jitted.lower(aparams, aopt, abatch)  # mock-warmup analogue
-        timings["lower_s"] = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        step_fn = lowered.compile()  # communicator-setup analogue
-        timings["compile_s"] = time.perf_counter() - t0
+        with span("liver/world/lower") as ph:
+            lowered = jitted.lower(aparams, aopt, abatch)  # mock-warmup analogue
+        timings["lower_s"] = ph.seconds
+        with span("liver/world/compile") as ph:
+            step_fn = lowered.compile()  # communicator-setup analogue
+        timings["compile_s"] = ph.seconds
 
     update_fn = None
     if split_step:
         # optimizer-only executable for the split-step commit: compiled
         # here, in the shadow thread, so the commit pause never pays it
-        t0 = time.perf_counter()
-        update_fn = build_update_world_fn(
-            cfg, mesh, parallel, opt_cfg, compression=compression, aot=aot
-        )
-        timings["update_compile_s"] = time.perf_counter() - t0
+        with span("liver/world/update_compile") as ph:
+            update_fn = build_update_world_fn(
+                cfg, mesh, parallel, opt_cfg, compression=compression, aot=aot
+            )
+        timings["update_compile_s"] = ph.seconds
 
     return WorldHandle(
         parallel=parallel,

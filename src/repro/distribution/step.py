@@ -26,6 +26,11 @@ from repro.distribution.sharding import (
 from repro.models import model as M
 from repro.optim import AdamWConfig, adamw_init, adamw_update
 
+# device-side names of the train step's halves (``jax.named_scope``): every
+# op of the loss and its gradient, and every op of the optimizer update
+FWD_SCOPE = "liver/fwd"
+OPT_SCOPE = "liver/opt"
+
 
 def make_grad_fn(
     cfg: ModelConfig,
@@ -39,9 +44,15 @@ def make_grad_fn(
     reconfiguration streams state while this runs on the old world, then
     applies ``make_update_fn`` on the new one)."""
 
+    def loss_of(p, batch):
+        # the backward's ops carry ``transpose(jvp(liver/fwd))`` in their
+        # op names, so one scope names both halves on the device trace
+        with jax.named_scope(FWD_SCOPE):
+            return M.loss_fn(cfg, p, batch, remat=remat)
+
     def grads_of(params, batch):
         (loss, metrics), grads = jax.value_and_grad(
-            lambda p: M.loss_fn(cfg, p, batch, remat=remat), has_aux=True
+            lambda p: loss_of(p, batch), has_aux=True
         )(params)
         return loss, metrics, grads
 
@@ -61,7 +72,7 @@ def make_grad_fn(
 
                 @jax.checkpoint
                 def body(acc, i):
-                    l, _ = M.loss_fn(cfg, p, mk_micro(i), remat=remat)
+                    l, _ = loss_of(p, mk_micro(i))
                     return acc + l, None
 
                 total, _ = jax.lax.scan(
@@ -119,11 +130,12 @@ def make_update_fn(opt_cfg: AdamWConfig, compression: str = "none"):
     were computed under)."""
 
     def update(grads, opt_state, params):
-        if compression == "int8_ef":
-            grads, opt_state = reshard_quant.compress_decompress_with_ef(
-                grads, opt_state
-            )
-        return adamw_update(opt_cfg, grads, opt_state, params)
+        with jax.named_scope(OPT_SCOPE):
+            if compression == "int8_ef":
+                grads, opt_state = reshard_quant.compress_decompress_with_ef(
+                    grads, opt_state
+                )
+            return adamw_update(opt_cfg, grads, opt_state, params)
 
     return update
 

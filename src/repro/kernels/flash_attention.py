@@ -157,13 +157,14 @@ def _flash_fwd(q, k, v, causal, window, scale, block_q, block_k, interpret):
 
 def _flash_bwd(causal, window, scale, block_q, block_k, interpret, res, g):
     q, k, v = res
-    _, vjp = jax.vjp(
-        lambda q, k, v: _ref.flash_attention_ref(
-            q, k, v, causal=causal, window=window, scale=scale
-        ),
-        q, k, v,
-    )
-    return vjp(g)
+    with jax.named_scope("flash_bwd"):
+        _, vjp = jax.vjp(
+            lambda q, k, v: _ref.flash_attention_ref(
+                q, k, v, causal=causal, window=window, scale=scale
+            ),
+            q, k, v,
+        )
+        return vjp(g)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)

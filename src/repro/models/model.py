@@ -163,13 +163,15 @@ def forward(
     enc_out = None
     if cfg.family == "encdec":
         enc_out = encode(cfg, params, batch["frames"], remat=remat)
-    x = shard_hints.constrain(L.embed_apply(params["embed"], tokens, adt), "activation")
+    with jax.named_scope("embed"):
+        x = shard_hints.constrain(L.embed_apply(params["embed"], tokens, adt), "activation")
     x, aux = T.stack_forward(
         params["blocks"], cfg, x, positions, causal=True, enc_out=enc_out, remat=remat
     )
-    x = L.rmsnorm_apply(params["final_norm"], x)
-    logits = L.lm_head_apply(params.get("lm_head"), params["embed"], x)
-    logits = shard_hints.constrain(logits, "logits")
+    with jax.named_scope("head"):
+        x = L.rmsnorm_apply(params["final_norm"], x)
+        logits = L.lm_head_apply(params.get("lm_head"), params["embed"], x)
+        logits = shard_hints.constrain(logits, "logits")
     return logits, aux
 
 
@@ -179,11 +181,12 @@ def loss_fn(
 ) -> tuple[jax.Array, dict]:
     logits, aux = forward(cfg, params, batch, remat=remat)
     tokens = batch["tokens"]
-    logits = logits[:, :-1].astype(jnp.float32)
-    targets = tokens[:, 1:]
-    logz = jax.scipy.special.logsumexp(logits, axis=-1)
-    tgt_logit = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    nll = (logz - tgt_logit).mean()
+    with jax.named_scope("head"):
+        logits = logits[:, :-1].astype(jnp.float32)
+        targets = tokens[:, 1:]
+        logz = jax.scipy.special.logsumexp(logits, axis=-1)
+        tgt_logit = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+        nll = (logz - tgt_logit).mean()
     loss = nll + aux_weight * aux
     return loss, {"nll": nll, "moe_aux": aux}
 

@@ -10,6 +10,7 @@ and gives pipeline parallelism a natural stage boundary.
 
 from __future__ import annotations
 
+import contextlib
 import math
 import os
 from typing import Any
@@ -103,33 +104,37 @@ def _block_apply_full(
     """Returns (x, aux_loss, kv) — kv is (k, v) when collect_kv and attn."""
     aux = jnp.zeros((), jnp.float32)
     kv = None
-    h = rmsnorm_apply(bp["ln1"], x)
-    if mixer == "attn":
-        if collect_kv:
-            out, k, v = attn.attn_forward(
-                bp["mixer"], cfg, h, positions, causal=causal, return_kv=True
-            )
-            kv = (k, v)
+    # attention and MLP ops carry "attn" / "mlp" in their op_name, which
+    # the benchmark's trace reader sums by layer
+    with jax.named_scope("attn") if mixer == "attn" else contextlib.nullcontext():
+        h = rmsnorm_apply(bp["ln1"], x)
+        if mixer == "attn":
+            if collect_kv:
+                out, k, v = attn.attn_forward(
+                    bp["mixer"], cfg, h, positions, causal=causal, return_kv=True
+                )
+                kv = (k, v)
+            else:
+                out = attn.attn_forward(bp["mixer"], cfg, h, positions, causal=causal)
+            h = out
         else:
-            out = attn.attn_forward(bp["mixer"], cfg, h, positions, causal=causal)
-        h = out
-    else:
-        if collect_kv:
-            h, state = ssm_mod.ssm_forward(bp["mixer"], cfg, h, return_state=True)
-            kv = state
-        else:
-            h = ssm_mod.ssm_forward(bp["mixer"], cfg, h)
+            if collect_kv:
+                h, state = ssm_mod.ssm_forward(bp["mixer"], cfg, h, return_state=True)
+                kv = state
+            else:
+                h = ssm_mod.ssm_forward(bp["mixer"], cfg, h)
     x = x + h
     if enc_out is not None and "cross" in bp:
         h = rmsnorm_apply(bp["ln_x"], x)
         x = x + attn.cross_attn_forward(bp["cross"], cfg, h, enc_out)
     if mlp != "none":
-        h = rmsnorm_apply(bp["ln2"], x)
-        if mlp == "moe":
-            aux = aux + moe_mod.moe_aux_loss(bp["mlp"], cfg, h)
-            h = moe_mod.moe_apply(bp["mlp"], cfg, h)
-        else:
-            h = mlp_apply(bp["mlp"], h, cfg.act)
+        with jax.named_scope("mlp"):
+            h = rmsnorm_apply(bp["ln2"], x)
+            if mlp == "moe":
+                aux = aux + moe_mod.moe_aux_loss(bp["mlp"], cfg, h)
+                h = moe_mod.moe_apply(bp["mlp"], cfg, h)
+            else:
+                h = mlp_apply(bp["mlp"], h, cfg.act)
         x = x + h
     # re-anchor the residual stream's sharding each block: without this
     # GSPMD resolves the FSDP-sharded contraction dims by ALL-REDUCING

@@ -33,7 +33,6 @@ the per-round byte accounting below reports both regimes truthfully.
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
@@ -42,6 +41,7 @@ from repro.core.records import ReuseRecordMixin
 from repro.core.resource_view import TensorSpec
 from repro.reshard.engine import ReshardEngine, StreamStats
 from repro.reshard.executors import LiveExecutor
+from repro.utils.spans import span
 
 
 def _layout_agrees(sh_old, sh_new, shape: tuple) -> bool:
@@ -116,7 +116,9 @@ class OverlapSession:
         max_inflight_rounds: int = 2,
         wire_policy=None,
         wire_bw_bytes_s: float | None = None,
+        gen_id: int = -1,
     ):
+        self.gen_id = gen_id  # the reconfiguration's id on its spans
         self.spec_map = {s.name: s for s in specs}
         self.plan = plan
         self.executor = LiveExecutor(
@@ -244,7 +246,7 @@ class OverlapSession:
         return sorted(l for l, s in self.streamed_at.items() if s < step)
 
     # ------------------------------------------------------------------
-    def _drain_rounds(self, keep: int) -> float:
+    def _drain_rounds(self, keep: int) -> None:
         """Block until all but the newest ``keep`` rounds' destination
         writes have landed. Later rounds donate earlier carries, so
         round-granular handles cannot be kept; tensors a newer in-flight
@@ -254,7 +256,6 @@ class OverlapSession:
         round. Those tensors' backpressure comes from the executor's
         bounded staging instead (per-device program order retires their
         scatters before anything newer)."""
-        t0 = time.perf_counter()
         while len(self._inflight) > keep:
             names = self._inflight.pop(0)
             for newer in self._inflight:
@@ -263,15 +264,12 @@ class OverlapSession:
                 leaf = self.executor.dst.get(n)
                 if leaf is not None and hasattr(leaf, "block_until_ready"):
                     leaf.block_until_ready()
-        return time.perf_counter() - t0
 
-    def drain(self) -> float:
+    def drain(self) -> None:
         """Full barrier: every dispatched round has landed. The only sync
         points are here and in ``resync`` — commit-time calls."""
-        dt = self._drain_rounds(0)
-        t0 = time.perf_counter()
+        self._drain_rounds(0)
         self.executor.block_until_ready()
-        return dt + (time.perf_counter() - t0)
 
     # ------------------------------------------------------------------
     def stream_next(self, src_leaves: dict[str, Any], step: int) -> int:
@@ -285,14 +283,14 @@ class OverlapSession:
         batch, self.pending = self.pending[: self.stream_k], self.pending[self.stream_k :]
         self.executor.update_sources(src_leaves)
         self.executor.begin_round()
-        t0 = time.perf_counter()
-        s = self.engine.run(batch)
-        dispatch_dt = time.perf_counter() - t0
+        with span("liver/stream/dispatch", gen=self.gen_id) as dispatch:
+            s = self.engine.run(batch)
+        dispatch_dt = dispatch.seconds
         self._inflight.append(self.executor.round_touched())
-        t1 = time.perf_counter()
-        self.executor.sync_staging()
-        drain_dt = time.perf_counter() - t1
-        drain_dt += self._drain_rounds(self.max_inflight_rounds - 1)
+        with span("liver/stream/drain", gen=self.gen_id) as drain:
+            self.executor.sync_staging()
+            self._drain_rounds(self.max_inflight_rounds - 1)
+        drain_dt = drain.seconds
         s.drain_seconds += drain_dt
         self.stats.merge(s)
         for l in batch:
@@ -337,15 +335,15 @@ class OverlapSession:
         self.executor.update_sources(src_leaves)
         self.executor.reset_round()
         self.executor.begin_round()
-        t0 = time.perf_counter()
-        s = self.engine.run(layers)
-        dispatch_dt = time.perf_counter() - t0
+        with span("liver/resync/dispatch", gen=self.gen_id) as dispatch:
+            s = self.engine.run(layers)
+        dispatch_dt = dispatch.seconds
         self._inflight.append(self.executor.round_touched())
-        t1 = time.perf_counter()
-        self.executor.sync_staging()
-        drain_dt = time.perf_counter() - t1
-        if drain:
-            drain_dt += self.drain()
+        with span("liver/resync/drain", gen=self.gen_id) as drain_span:
+            self.executor.sync_staging()
+            if drain:
+                self.drain()
+        drain_dt = drain_span.seconds
         s.drain_seconds += drain_dt
         self.stats.merge(s)
         for l in layers:

@@ -5,7 +5,6 @@ from repro.utils.pytree import (
     tree_param_count,
     path_str,
 )
-from repro.utils.timing import Timer, now
 
 __all__ = [
     "axes_paths",
@@ -13,6 +12,4 @@ __all__ = [
     "tree_bytes",
     "tree_param_count",
     "path_str",
-    "Timer",
-    "now",
 ]
