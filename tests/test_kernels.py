@@ -299,25 +299,73 @@ def test_pack_then_scatter_roundtrip():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("window", [0, 64])
-def test_flash_attention_vjp_matches_reference_grad(window):
-    """jax.grad through the kernel (custom_vjp) == jax.grad of the oracle."""
-    q = _rand((1, 256, 4, 32))
-    k = _rand((1, 256, 2, 32))
-    v = _rand((1, 256, 2, 32))
-    w = _rand((1, 256, 4, 32))
+# bf16 gradients: the kernels feed p and dS to the MXU in bf16 and the
+# oracle keeps them in f32, so the gap is bf16 rounding, relative to the
+# largest entry (measured up to ~5e-3)
+BF16_GRAD_TOL = 2e-2
+
+
+@pytest.mark.parametrize(
+    "b,s,t,h,kh,d,causal,window,dtype",
+    [
+        # GQA rep 2, 2 blocks; the ids of the two cases this test began with
+        pytest.param(1, 256, 256, 4, 2, 32, True, 0, jnp.float32, id="0"),
+        pytest.param(1, 256, 256, 4, 2, 32, True, 64, jnp.float32, id="64"),
+        (1, 384, 384, 4, 4, 64, True, 0, jnp.float32),  # MHA, 3 blocks
+        (1, 384, 384, 4, 1, 64, True, 100, jnp.float32),  # MQA, window < block
+        (2, 256, 256, 8, 2, 128, False, 0, jnp.float32),  # GQA rep 4, non-causal
+        (1, 768, 768, 4, 1, 32, False, 200, jnp.float32),  # 3 blocks of 256
+        (1, 1536, 1536, 2, 1, 32, True, 0, jnp.float32),  # 3 blocks of 512
+        (1, 256, 640, 4, 2, 64, True, 0, jnp.float32),  # t > s: right-aligned
+        (1, 256, 768, 4, 2, 32, True, 128, jnp.float32),  # a k block no query sees
+        (1, 384, 384, 4, 2, 128, True, 0, jnp.bfloat16),
+        (1, 256, 512, 8, 2, 64, True, 96, jnp.bfloat16),
+    ],
+)
+def test_flash_attention_vjp_matches_reference_grad(b, s, t, h, kh, d, causal, window, dtype):
+    """jax.grad through the kernel (custom_vjp: the Pallas backward) ==
+    jax.grad of the oracle."""
+    q, w = _rand((b, s, h, d), dtype), _rand((b, s, h, d))
+    k, v = _rand((b, t, kh, d), dtype), _rand((b, t, kh, d), dtype)
 
     def loss(fn):
-        return lambda q, k, v: (fn(q, k, v) * w).sum()
+        return lambda q, k, v: (fn(q, k, v).astype(jnp.float32) * w).sum()
 
-    kern = loss(
-        lambda q, k, v: flash_attention_pallas(q, k, v, window=window, interpret=True)
-    )
-    orac = loss(lambda q, k, v: ref.flash_attention_ref(q, k, v, window=window))
+    kern = loss(lambda q, k, v: flash_attention_pallas(
+        q, k, v, causal=causal, window=window, interpret=True))
+    orac = loss(lambda q, k, v: ref.flash_attention_ref(q, k, v, causal=causal, window=window))
     got = jax.grad(kern, argnums=(0, 1, 2))(q, k, v)
     exp = jax.grad(orac, argnums=(0, 1, 2))(q, k, v)
     for g, e in zip(got, exp):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(e), atol=1e-5, rtol=1e-5)
+        g, e = np.asarray(g, np.float32), np.asarray(e, np.float32)
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(g, e, atol=1e-5, rtol=1e-5)
+        else:
+            tol = BF16_GRAD_TOL
+            np.testing.assert_allclose(g, e, atol=tol * np.abs(e).max(), rtol=tol)
+
+
+@pytest.mark.parametrize(
+    "s,t,causal,window",
+    [(256, 256, True, 0), (384, 384, True, 100), (256, 768, True, 128), (256, 256, False, 64)],
+)
+def test_flash_lse_pass_matches_logsumexp(s, t, causal, window):
+    """The backward's lse pass == logsumexp of the oracle's masked scores,
+    rows a window leaves partly empty included."""
+    from repro.kernels.flash_attention import _bwd_block, _lse_pass
+
+    b, h, kh, d = 1, 4, 2, 32
+    q, k = _rand((b, h, s, d)), _rand((b, kh, t, d))
+    scale = d**-0.5
+    got = _lse_pass(q, k, causal, window, scale, _bwd_block(s), _bwd_block(t), True)
+    scores = jnp.einsum("bhsd,bhtd->bhst", q * scale, jnp.repeat(k, h // kh, axis=1))
+    qpos, kpos = jnp.arange(s)[:, None] + (t - s), jnp.arange(t)[None, :]
+    keep = kpos <= qpos if causal else jnp.ones((s, t), bool)
+    if window > 0:
+        keep &= kpos > qpos - window
+    exp = jax.nn.logsumexp(jnp.where(keep, scores, -jnp.inf), axis=-1)
+    assert got.shape == (b, h, 1, s)
+    np.testing.assert_allclose(np.asarray(got[:, :, 0]), np.asarray(exp), atol=1e-5, rtol=1e-5)
 
 
 @pytest.mark.parametrize("kernel", ["pack", "scatter", "relayout"])

@@ -145,12 +145,26 @@ def test_flash_attention_forward_compiles(one_chip, arch):
 
 @pytest.mark.parametrize("arch", sorted(ATTN))
 def test_flash_attention_grad_compiles(one_chip, arch):
+    """Forward and the Pallas backward (lse pass, dQ, dK/dV) compile; only
+    the forward has a single bf16 output of q's heads-major shape, which is
+    how the benchmark's ``flash_fwd_roofline`` finds it."""
+
     def loss(q, k, v):
         return flash_attention_pallas(q, k, v).astype(jnp.float32).sum()
 
-    _compile(
-        jax.value_and_grad(loss, argnums=(0, 1, 2)), *_qkv(one_chip, *ATTN[arch])
-    )
+    heads, kv, d = ATTN[arch]
+    qkv = _qkv(one_chip, heads, kv, d)
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), *qkv)
+    b, s = qkv[0].shape[:2]
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    forward = [line for line in calls if f"= bf16[{b},{heads},{s},{d}]" in line]
+    assert len(forward) == 1
+    backward = [line for line in calls if "flash_bwd" in line]
+    assert len(backward) == 3 and len(calls) == 4
+    assert any(f"= f32[{b},{heads},1,{s}]" in line for line in backward)  # lse
+    assert any(f"= f32[{b},{heads},{s},{d}]" in line for line in backward)  # dQ
+    assert any(f"= (bf16[{b},{kv},{s},{d}]" in line for line in backward)  # dK, dV
 
 
 # ---------------------------------------------------------------------------
