@@ -4,7 +4,8 @@ chip. Not part of a benchmark run.
     python3 bench/calibrate.py --workload <cell> --seeds 1-12 --control-seeds 1-3 [--out f.json]
 
 For every seed of ``--seeds`` the program (one controller, restarted from
-each seed) is compared with the plain reference: the lower readings. For
+each seed) is compared with the plain reference that the configuration's
+``reference`` key names: the lower readings. For
 every seed of ``--control-seeds`` the control (the reference in fp8 put in
 the program's place), half of the batch left out (the reference with
 ``fault="half_batch"`` in the program's place) and, in a cell whose driver
@@ -133,7 +134,7 @@ def main() -> None:
         raise SystemExit(f"needs {wl['chips']} TPU chips")
     seeds, control = _seeds(args.seeds), _seeds(args.control_seeds)
     ctx = harness.RunContext(name=args.workload, workload=wl, conf=conf,
-                             cfg=model.model_config(conf), seed=seeds[0], seconds=0,
+                             cfg=model.family(conf).model_config(conf), seed=seeds[0], seconds=0,
                              trace=False, devices=devices, chips=wl["chips"],
                              t0=time.perf_counter(), counter=common.WindowCounters())
     rows, summary = table(ctx, seeds, control)

@@ -97,7 +97,8 @@ def reseed(ctrl, ctx) -> None:
     weights, zero AdamW state, step 0 and the seed's batches."""
     p_sh, o_sh = ctx.state_shardings
     ctrl.params = ctrl.opt_state = None
-    params = jax.jit(functools.partial(model.init_params, ctx.conf), out_shardings=p_sh)(
+    init_params = model.family(ctx.conf).init_params
+    params = jax.jit(functools.partial(init_params, ctx.conf), out_shardings=p_sh)(
         model.params_key(ctx.seed))
     if jax.tree_util.tree_structure(params) != jax.tree_util.tree_structure(p_sh):
         raise model.BenchError("the program's parameter layout differs from the benchmark's")
@@ -119,8 +120,9 @@ def _grad_norms_fn(b1: float):
 @functools.lru_cache(maxsize=None)
 def _delta_norms_fn(conf_key: str):
     conf = json.loads(conf_key)
+    init_params = model.family(conf).init_params
     return jax.jit(lambda p, k: model.leaf_norms(
-        jax.tree_util.tree_map(jnp.subtract, p, model.init_params(conf, k))))
+        jax.tree_util.tree_map(jnp.subtract, p, init_params(conf, k))))
 
 
 def first_grad_norms(ctrl) -> dict[str, float]:

@@ -3,9 +3,11 @@ plain reference, and the result line.
 
 Everything a cell is made of is found by name: ``workloads/<cell>.json``
 names its configuration (``configs/<config>.json``), its traffic driver
-(``drivers/<driver>.py``) and its limits; the per-layer metrics it reports
-are the ``per_layer`` entries of ``BENCHMARK.json`` that list it (or list no
-cells), each read by ``metrics/<metric>.py``.
+(``drivers/<driver>.py``) and its limits; the configuration names its model
+family (``families/<family>.py``) and its plain reference (``<reference>.py``,
+trained by ``reference.py``); the per-layer metrics it reports are the
+``per_layer`` entries of ``BENCHMARK.json`` that list it (or list no cells),
+each read by ``metrics/<metric>.py``.
 """
 
 from __future__ import annotations
@@ -121,6 +123,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, t0: float,
     a run on the CPU with it."""
     import jax
 
+    from bench import reference
     from bench import trace as trace_mod
     from bench.drivers.common import WindowCounters
 
@@ -140,7 +143,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, t0: float,
         jax.config.update("jax_compilation_cache_dir", str(ROOT / ".jax_cache"))
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
-    ctx = RunContext(name=name, workload=wl, conf=conf, cfg=model.model_config(conf),
+    ctx = RunContext(name=name, workload=wl, conf=conf, cfg=model.family(conf).model_config(conf),
                      seed=seed, seconds=seconds, trace=trace, devices=devices,
                      chips=wl["chips"], t0=t0, counter=WindowCounters())
     driver = importlib.import_module(f"bench.drivers.{wl['driver']}")
@@ -153,9 +156,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, t0: float,
     held = (devices[0].memory_stats() or {}).get("bytes_in_use")
     log(f"device 0 holds {held} bytes before the reference")
     t_ref = time.perf_counter()
-    ref_mod = importlib.import_module(f"bench.{conf['reference']}")
-    ref = ref_mod.train_readings(conf, wl["optimizer"], seed, out.batches, device=devices[0])
-    checks = ref_mod.compare(out.readings, ref)
+    ref = reference.train_readings(conf, wl["optimizer"], seed, out.batches, device=devices[0])
+    checks = reference.compare(out.readings, ref)
     limits = wl["limits"]
     correct = set(checks) == set(limits) and all(
         math.isfinite(checks[k]) and checks[k] <= limits[k] for k in checks)

@@ -1,6 +1,7 @@
 """Chip 0's self time per step of the flash-attention backward: ops under
-the ``flash_bwd`` scope of the kernel's backward rule (the reference VJP,
-recomputed; ``flash_attention.BACKWARD``)."""
+the ``flash_bwd`` scope of the kernel's backward rule, the Pallas backward
+(the lse pass, the dK/dV and dQ kernels, D and the layout transposes;
+``flash_attention.BACKWARD``)."""
 
 from bench import phases
 

@@ -1,18 +1,26 @@
-"""Plain float32 reference of a dense decoder's training step.
+"""Plain float32 reference of a decoder's training step.
 
-Written from the published model descriptions (Qwen2 / Qwen3 in Hugging Face
-``transformers``): RMSNorm, rotary embeddings on the two halves of each head,
-grouped-query causal attention with optional q/k/v bias and per-head q/k
-RMSNorm, a SwiGLU MLP, a tied or untied head and mean next-token cross
-entropy; then AdamW with global-norm clipping and a warm-up + cosine learning
-rate. It imports nothing of the program and takes nothing the program made:
-weights and batches come from ``model.py``.
+The trainer (``train_readings``, ``compare``) serves every configuration:
+AdamW with global-norm clipping and a warm-up + cosine learning rate over
+the loss of the module that the configuration's ``reference`` key names
+(``bench/<reference>.py``, which has ``loss_fn(conf, params, tokens, matmul,
+fault)``), from the weights of its family (``bench/families/``).
+
+This module is also the reference of dense decoders (``"reference":
+"reference"``), written from the published model descriptions (Qwen2 / Qwen3
+in Hugging Face ``transformers``): RMSNorm, rotary embeddings on the two
+halves of each head, grouped-query causal attention with optional q/k/v bias
+and per-head q/k RMSNorm, a SwiGLU MLP, a tied or untied head and mean
+next-token cross entropy. Another architecture's reference can reuse its
+sublayers. It imports nothing of the program and takes nothing the program
+made: weights and batches come from the benchmark's own code.
 
 Every matrix product runs at ``highest`` precision. ``matmul="fp8"`` runs
 them on per-tensor-scaled float8_e4m3fn operands in both passes instead: the
 control, one precision step below the bf16 the configurations state.
 ``fault="half_batch"`` takes the loss over half of the batch (half the rows,
 or half the positions of a single row): a fault the comparison must catch.
+Every reference honours both.
 
 Memory: layers are rematerialised one by one, attention runs in blocks of
 query rows and the head + loss in blocks of positions, so the 8-layer cells
@@ -23,6 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import importlib
 import math
 
 import jax
@@ -76,7 +85,13 @@ def _einsum(spec: str, matmul: str):
     return mm
 
 
-def _rms(x, scale, eps):
+def mm_for(matmul: str):
+    """``mm(spec)(a, b)``: an einsum at ``highest`` precision, or on fp8
+    operands where ``matmul="fp8"``."""
+    return lambda spec: _einsum(spec, matmul)
+
+
+def rms(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale
 
 
@@ -105,37 +120,51 @@ def _attention(q, k, v, mm):
     return jnp.concatenate(outs, axis=1)
 
 
-def _layer(conf, mm, x, p):
+def attention_sublayer(conf, mm, x, p):
+    """x plus the attention of its RMSNorm (``ln1``, ``mixer``)."""
     b, s, d = x.shape
     h, kh, hd = conf["num_attention_heads"], conf["num_key_value_heads"], conf["head_dim"]
     eps, a = conf["rms_norm_eps"], p["mixer"]
-    hin = _rms(x, p["ln1"]["scale"], eps)
+    hin = rms(x, p["ln1"]["scale"], eps)
     q, k, v = (mm("bsd,de->bse")(hin, a[w]) for w in ("wq", "wk", "wv"))
     if conf["attention_bias"]:
         q, k, v = q + a["bq"], k + a["bk"], v + a["bv"]
     q, k, v = q.reshape(b, s, h, hd), k.reshape(b, s, kh, hd), v.reshape(b, s, kh, hd)
     if conf["qk_norm"]:
-        q, k = _rms(q, a["q_norm"], eps), _rms(k, a["k_norm"], eps)
+        q, k = rms(q, a["q_norm"], eps), rms(k, a["k_norm"], eps)
     theta = float(conf["rope_theta"])
     o = _attention(_rope(q, theta), _rope(k, theta), v, mm).reshape(b, s, h * hd)
-    x = x + mm("bse,ed->bsd")(o, a["wo"])
-    hin = _rms(x, p["ln2"]["scale"], eps)
-    m = p["mlp"]
-    gate = jax.nn.silu(mm("bsd,df->bsf")(hin, m["wi_gate"]))
-    up = mm("bsd,df->bsf")(hin, m["wi_up"])
-    return x + mm("bsf,fd->bsd")(gate * up, m["wo"])
+    return x + mm("bse,ed->bsd")(o, a["wo"])
+
+
+def swiglu(mm, h, m):
+    """SwiGLU of h (..., d) under ``wi_gate``, ``wi_up`` and ``wo``."""
+    gate = jax.nn.silu(mm("bsd,df->bsf")(h, m["wi_gate"]))
+    up = mm("bsd,df->bsf")(h, m["wi_up"])
+    return mm("bsf,fd->bsd")(gate * up, m["wo"])
+
+
+def _layer(conf, mm, x, p):
+    x = attention_sublayer(conf, mm, x, p)
+    return x + swiglu(mm, rms(x, p["ln2"]["scale"], conf["rms_norm_eps"]), p["mlp"])
 
 
 def loss_fn(conf, params, tokens, matmul="f32", fault=None):
     """Mean next-token cross entropy of ``tokens`` (b, s) under ``params``."""
-    mm = lambda spec: _einsum(spec, matmul)
+    mm = mm_for(matmul)
     x = params["embed"]["tok"][tokens]
 
     def body(x, p):
         return jax.checkpoint(functools.partial(_layer, conf, mm))(x, p), None
 
     x, _ = jax.lax.scan(body, x, params["blocks"]["pos0"])
-    x = _rms(x, params["final_norm"]["scale"], conf["rms_norm_eps"])
+    return head_loss(conf, mm, params, x, tokens, fault)
+
+
+def head_loss(conf, mm, params, x, tokens, fault=None):
+    """Mean next-token cross entropy from the last layer's output x (b, s, d):
+    the final RMSNorm, the tied or untied head, and ``fault``."""
+    x = rms(x, params["final_norm"]["scale"], conf["rms_norm_eps"])
     b, s, d = x.shape
     if conf["tie_word_embeddings"]:
         head, spec = params["embed"]["tok"], "nd,vd->nv"
@@ -167,9 +196,9 @@ def learning_rate(opt: dict, count):
     return opt["learning_rate"] * warm * (opt["min_lr_ratio"] + (1 - opt["min_lr_ratio"]) * cos)
 
 
-def _train_step(conf, opt, matmul, fault, params, mu, nu, count, tokens):
+def _train_step(conf, opt, loss_of, matmul, fault, params, mu, nu, count, tokens):
     loss, grads = jax.value_and_grad(
-        lambda p: loss_fn(conf, p, tokens, matmul, fault))(params)
+        lambda p: loss_of(conf, p, tokens, matmul, fault))(params)
     leaves = jax.tree_util.tree_leaves(grads)
     gnorm = jnp.sqrt(sum(jnp.sum(g * g) for g in leaves))
     clip = jnp.minimum(1.0, opt["grad_clip"] / (gnorm + 1e-9))
@@ -190,17 +219,20 @@ def _train_step(conf, opt, matmul, fault, params, mu, nu, count, tokens):
 
 def train_readings(conf: dict, opt: dict, seed: int, batches: list[np.ndarray],
                    device=None, matmul: str = "f32", fault=None) -> Readings:
-    """Train from the seed's weights over ``batches`` on one device and read
-    the losses, the first clipped gradient and the change of the weights."""
+    """Train from the seed's weights (the configuration's family) over
+    ``batches`` on one device under its reference's loss, and read the
+    losses, the first clipped gradient and the change of the weights."""
     device = device or jax.devices()[0]
     key = model.params_key(seed)
+    init_params = model.family(conf).init_params
+    loss_of = importlib.import_module(f"bench.{conf['reference']}").loss_fn
     with jax.default_matmul_precision("highest"), jax.default_device(device):
-        init = jax.jit(functools.partial(model.init_params, conf))
+        init = jax.jit(functools.partial(init_params, conf))
         params = init(key)
         mu = jax.tree_util.tree_map(jnp.zeros_like, params)
         nu = jax.tree_util.tree_map(jnp.zeros_like, params)
         count = jnp.zeros((), jnp.int32)
-        step = jax.jit(functools.partial(_train_step, conf, opt, matmul, fault),
+        step = jax.jit(functools.partial(_train_step, conf, opt, loss_of, matmul, fault),
                        donate_argnums=(0, 1, 2))
         losses, grad_norms = [], None
         for tokens in batches:
@@ -210,7 +242,7 @@ def train_readings(conf: dict, opt: dict, seed: int, batches: list[np.ndarray],
                 grad_norms = model.flat_norms(jax.device_get(gn))
         del mu, nu
         delta = jax.jit(lambda p, k: model.leaf_norms(
-            jax.tree_util.tree_map(jnp.subtract, p, model.init_params(conf, k))))
+            jax.tree_util.tree_map(jnp.subtract, p, init_params(conf, k))))
         update_norms = model.flat_norms(jax.device_get(delta(params, key)))
     return Readings(losses, grad_norms, update_norms)
 

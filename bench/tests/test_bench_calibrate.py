@@ -19,8 +19,8 @@ def test_control_and_half_batch_read_above_the_program(tmp_path):
     wl = model.load("workloads", "tiny.steady", root)
     conf = model.load("configs", wl["config"], root)
     ctx = harness.RunContext(name="tiny.steady", workload=wl, conf=conf,
-                             cfg=model.model_config(conf), seed=1, seconds=0, trace=False,
-                             devices=jax.devices(), chips=1, t0=time.perf_counter(),
+                             cfg=model.family(conf).model_config(conf), seed=1, seconds=0,
+                             trace=False, devices=jax.devices(), chips=1, t0=time.perf_counter(),
                              counter=common.WindowCounters())
     rows, summary = calibrate.table(ctx, [1, 2, 3], [1, 2, 3])
     assert [r["kind"] for r in rows].count("program") == 3

@@ -1,4 +1,5 @@
-"""FLOP and parameter counts of the cells against the program's own count."""
+"""FLOP and parameter counts of the cells (the dense family's) against the
+program's own count."""
 
 import json
 import sys
@@ -8,6 +9,7 @@ import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
 from bench import flops, model  # noqa: E402
+from bench.families import dense  # noqa: E402
 from bench.tests._tiny import tiny_conf  # noqa: E402
 
 
@@ -16,8 +18,8 @@ def test_param_count_matches_the_program(name, params):
     from repro.models.model import analytic_param_count
 
     conf = model.load("configs", name)
-    assert flops.param_count(conf) == params
-    assert analytic_param_count(model.model_config(conf)) == params
+    assert dense.param_count(conf) == params
+    assert analytic_param_count(dense.model_config(conf)) == params
 
 
 @pytest.mark.parametrize("variant", [dict(tie_word_embeddings=False), dict(attention_bias=True)])
@@ -25,17 +27,17 @@ def test_param_count_variants_match_the_program(variant):
     from repro.models.model import analytic_param_count
 
     conf = tiny_conf(**variant)
-    assert flops.param_count(conf) == analytic_param_count(model.model_config(conf))
+    assert dense.param_count(conf) == analytic_param_count(dense.model_config(conf))
 
 
 def test_model_flops_of_the_cells():
     q3 = model.load("configs", "qwen3-1.7b")
-    total = flops.model_flops_per_step(q3, 1, 4096)
+    total = dense.flops_per_step(q3, 1, 4096)
     attention = 3 * 4 * 16 * 128 * 8 * 4096 * 4097 // 2
     assert total == 6 * 713_818_112 * 4096 + attention
     assert total == pytest.approx(19.19e12, rel=1e-3)
     q25 = model.load("configs", "qwen2.5-1.5b")
-    assert flops.model_flops_per_step(q25, 4, 1024) == pytest.approx(15.25e12, rel=1e-3)
+    assert dense.flops_per_step(q25, 4, 1024) == pytest.approx(15.25e12, rel=1e-3)
 
 
 def test_flash_forward_counts():

@@ -1,5 +1,6 @@
-"""The plain reference against the program's model on seeded weights (CPU,
-tiny widths, float32 in both), for each attention and head variant."""
+"""The plain reference against the program's model on the dense family's
+seeded weights (CPU, tiny widths, float32 in both), for each attention and
+head variant."""
 
 import functools
 import sys
@@ -12,6 +13,7 @@ import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
 from bench import model, reference  # noqa: E402
+from bench.families import dense  # noqa: E402
 from bench.tests._tiny import tiny_conf  # noqa: E402
 
 VARIANTS = {
@@ -28,8 +30,8 @@ def test_reference_matches_program_loss_and_grads(variant):
 
     conf = tiny_conf(**VARIANTS[variant])
     conf["assumed"] = dict(conf["assumed"], compute_dtype="float32")
-    cfg = model.model_config(conf)
-    params = jax.jit(functools.partial(model.init_params, conf))(model.params_key(7))
+    cfg = dense.model_config(conf)
+    params = jax.jit(functools.partial(dense.init_params, conf))(model.params_key(7))
     tokens = jnp.asarray(model.SeededTokens(7, conf["vocab_size"], 2, 32).global_batch_at(0))
 
     with jax.default_matmul_precision("highest"):
