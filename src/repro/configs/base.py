@@ -56,11 +56,30 @@ class ModelConfig:
     sliding_window: int = 0  # 0 => full attention
     rope_theta: float = 10_000.0
     tie_embeddings: bool = False
+    norm_eps: float = 1e-6  # every RMSNorm's epsilon (layers, q/k heads, final)
+    # per-layer mixers, one of "attn" | "ssm" | "conv" for each layer; empty:
+    # from ``family`` and ``attn_period`` (see layer_kind)
+    layer_types: tuple[str, ...] = ()
+    conv_kernel: int = 3  # taps of the gated short convolution ("conv" mixer)
     # MoE
-    num_experts: int = 0
+    num_experts: int = 0  # the router's width: every expert of the model
     experts_per_token: int = 0
     moe_period: int = 1  # a layer l is MoE iff num_experts>0 and l % moe_period == moe_period-1
+    dense_layers: int = 0  # leading layers with a dense MLP before the first MoE layer
+    expert_d_ff: int = 0  # 0 => d_ff
+    # "softmax": top-k of the softmax, with the Switch load-balancing loss;
+    # "sigmoid_bias": top-k of sigmoid + a bias that has no gradient, weighted
+    # by the sigmoid alone, no auxiliary loss. Either renormalises its k
+    # weights, then scales them by ``routed_scale``.
+    router: str = "softmax"
+    routed_scale: float = 1.0
+    # the experts this world computes: ``experts_held`` (0 => all) of them
+    # from ``first_expert`` on; pairs routed elsewhere add nothing here
+    experts_held: int = 0
+    first_expert: int = 0
     moe_shared_expert: bool = False
+    # read by no layer since dispatch became dropless; still a field because
+    # configurations of the benchmark's tests set it
     moe_capacity_factor: float = 1.25
     # SSM / hybrid
     ssm_state: int = 0
@@ -77,13 +96,36 @@ class ModelConfig:
     # provenance tag from the assignment table
     source: str = ""
 
+    def __post_init__(self):
+        if self.layer_types and len(self.layer_types) != self.num_layers:
+            raise ValueError(f"{self.name}: {len(self.layer_types)} layer_types for "
+                             f"{self.num_layers} layers")
+        if set(self.layer_types) - {"attn", "ssm", "conv"}:
+            raise ValueError(f"{self.name}: unknown mixer in {self.layer_types}")
+        if self.router not in ("softmax", "sigmoid_bias"):
+            raise ValueError(f"{self.name}: unknown router {self.router!r}")
+        if self.first_expert < 0 or self.first_expert + self.held_experts > self.num_experts:
+            raise ValueError(f"{self.name}: experts {self.first_expert}.."
+                             f"{self.first_expert + self.held_experts - 1} are not among "
+                             f"the router's {self.num_experts}")
+
     # ---- derived -----------------------------------------------------
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.num_heads
 
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def resolved_expert_d_ff(self) -> int:
+        return self.expert_d_ff or self.d_ff
+
     def layer_kind(self, layer_idx: int) -> str:
-        """'attn' | 'ssm' for the mixer of decoder layer ``layer_idx``."""
+        """'attn' | 'ssm' | 'conv' for the mixer of decoder layer ``layer_idx``."""
+        if self.layer_types:
+            return self.layer_types[layer_idx]
         if self.family == "ssm":
             return "ssm"
         if self.family == "hybrid" and self.attn_period > 0:
@@ -95,8 +137,10 @@ class ModelConfig:
         return "attn"
 
     def is_moe_layer(self, layer_idx: int) -> bool:
-        return self.num_experts > 0 and (
-            layer_idx % self.moe_period == self.moe_period - 1
+        return (
+            self.num_experts > 0
+            and layer_idx >= self.dense_layers
+            and layer_idx % self.moe_period == self.moe_period - 1
         )
 
     @property
@@ -138,8 +182,6 @@ class ModelConfig:
             d_ff=128,
             vocab_size=512,
             num_experts=min(self.num_experts, 4),
-            # no token dropping in smoke/consistency tests (capacity >= k*s)
-            moe_capacity_factor=float(max(self.num_experts, 1)),
             sliding_window=min(self.sliding_window, 64) if self.sliding_window else 0,
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             ssm_chunk=8 if self.ssm_state else 64,
@@ -157,11 +199,8 @@ class ModelConfig:
 
 @dataclass(frozen=True)
 class ParallelConfig:
-    """Logical parallel decomposition. world = dp * pp * tp * ep_outer.
-
-    ``ep`` subdivides expert storage *within* the tp dimension group for MoE
-    models when ``ep_inner`` is True; by default ep is an independent axis.
-    """
+    """Logical parallel decomposition. world = dp * pp * tp * ep, with ep
+    an axis of its own that shards the stacked experts."""
 
     dp: int = 1
     pp: int = 1

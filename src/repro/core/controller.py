@@ -193,6 +193,9 @@ class LiveRController:
         self.ledger = GoodputLedger()
         self.records: list[ReconfigRecord] = []
         self.iteration_times: list[float] = []
+        # (token, choice) pairs routed to the experts this world holds, over
+        # every step this controller ran (the steps' ``moe_local_rows``)
+        self.moe_local_rows = 0
         self.step = 0
         self.data = data or SyntheticLM(cfg.vocab_size, seq_len, global_batch, seed)
         self.ckpt_dir = ckpt_dir
@@ -821,8 +824,16 @@ class LiveRController:
                 with span("liver/wait", step=step) as wait:
                     jax.block_until_ready(metrics["loss"])
                     t1 = time.perf_counter()
-                    losses.append(float(metrics["loss"]))
-                with span("liver/boundary", step=step) as boundary:
+                    # an MoE model's routed-rows count comes back in the
+                    # loss's transfer
+                    loss, rows = jax.device_get(
+                        (metrics["loss"], metrics.get("moe_local_rows")))
+                    losses.append(float(loss))
+                # the boundary span carries the count, so that a reader of
+                # the spans finds it beside the step's times
+                counts = {} if rows is None else {"moe_local_rows": int(rows)}
+                with span("liver/boundary", step=step, **counts) as boundary:
+                    self.moe_local_rows += counts.get("moe_local_rows", 0)
                     t0 = feed.t0
                     self.iteration_times.append(t1 - t0)
                     self.ledger.record(t0, t1, "train", self.world.parallel.world_size)

@@ -25,7 +25,7 @@ from __future__ import annotations
 import bisect
 import re
 from dataclasses import dataclass, field
-from typing import Callable, Iterable, Optional
+from typing import Callable, Iterable, Mapping, Optional
 
 from repro.configs.base import ParallelConfig
 from repro.core.resource_view import (
@@ -37,7 +37,7 @@ from repro.core.resource_view import (
     view_of,
 )
 
-_POS_RE = re.compile(r"/pos(\d+)/")
+_STACK_RE = re.compile(r"/(lead|pos)(\d+)/")
 
 
 @dataclass(frozen=True)
@@ -166,12 +166,14 @@ def _itemsize(dtype: str) -> int:
 
 
 def _layer_id(
-    spec: TensorSpec, cell_lo: int, num_positions: int
+    spec: TensorSpec, cell_lo: int, stacks: Mapping[str, tuple[int, int]]
 ) -> int:
-    """Global layer id of a unit stacked-axis slice starting at cell_lo."""
-    m = _POS_RE.search(spec.name)
-    j = int(m.group(1)) if m else 0
-    return cell_lo * num_positions + j
+    """Global layer id of a unit stacked-axis slice starting at cell_lo;
+    ``stacks`` maps a stack's prefix to (its first layer, its period)."""
+    m = _STACK_RE.search(spec.name)
+    prefix, j = (m.group(1), int(m.group(2))) if m else ("pos", 0)
+    first, period = stacks[prefix]
+    return first + cell_lo * period + j
 
 
 def replica_candidates(
@@ -241,18 +243,22 @@ def plan_transfer(
     layer_granular: bool = True,
     num_positions: int = 1,
     allowed_src: Optional[frozenset[int]] = None,
+    stacks: Optional[Mapping[str, tuple[int, int]]] = None,
 ) -> TransferPlan:
     """Compute the full transfer plan between two configurations.
 
     layer_granular: additionally cut the stacked-layers dim into unit slices
     so execution can stream one *model layer* at a time (Algorithm 1);
     ``num_positions`` is the block-program period (for global layer ids).
+    ``stacks`` (``transformer.layer_stacks``) takes its place for a model
+    scanned as more than one stack: each prefix's (first layer, period).
 
     allowed_src: survivor-constrained planning (DESIGN.md §15) — only these
     source ranks may donate a cell. Cells whose whole replica group fell
     outside the set come back as ``kind == "lost"`` with ``src_rank == -1``;
     the caller must repair them (parity) or abandon the plan.
     """
+    stacks = stacks or {"pos": (0, num_positions)}
     tasks: list[TransferTask] = []
     for spec in specs:
         itemsize = _itemsize(spec.dtype)
@@ -284,7 +290,7 @@ def plan_transfer(
                         v_dst,
                         itemsize,
                         source_policy,
-                        num_positions,
+                        stacks,
                         ldim,
                         allowed_src,
                     )
@@ -309,7 +315,7 @@ def _emit_cell(
     v_dst: View,
     itemsize: int,
     policy: str,
-    num_positions: int,
+    stacks: Mapping[str, tuple[int, int]],
     ldim: Optional[int],
     allowed_src: Optional[frozenset[int]] = None,
 ) -> None:
@@ -319,7 +325,7 @@ def _emit_cell(
         nbytes *= hi - lo
     layer = -1
     if ldim is not None:
-        layer = _layer_id(spec, bounds[ldim][0], num_positions)
+        layer = _layer_id(spec, bounds[ldim][0], stacks)
     if allowed_src is not None:
         candidates = [r for r in candidates if r in allowed_src]
         if not candidates:

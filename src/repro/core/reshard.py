@@ -184,7 +184,7 @@ def plan_state_transfer(
     DESIGN.md §15); cells nobody in the set can donate come back as
     ``kind == "lost"``.
     """
-    from repro.models.transformer import block_program
+    from repro.models.transformer import layer_stacks
 
     specs = build_tensor_specs(cfg, include_optimizer=True, zero_sharding=False)
     plan = plan_transfer(
@@ -193,7 +193,7 @@ def plan_state_transfer(
         cfg_dst,
         source_policy=source_policy,
         layer_granular=True,
-        num_positions=len(block_program(cfg)),
+        stacks=layer_stacks(cfg),
         allowed_src=allowed_src,
     )
     return specs, plan

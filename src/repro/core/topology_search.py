@@ -45,7 +45,9 @@ def feasible_configs(
     max_pp: int = 8,
 ) -> list[ParallelConfig]:
     """All (dp, pp, tp) with dp·pp·tp == world respecting divisibility:
-    dp | global_batch, pp | n_periods, tp bounded by head/ffn divisibility."""
+    dp | global_batch, pp | n_periods, tp bounded by head/ffn divisibility;
+    pp 1 where leading dense layers form a stack of their own, which
+    pipeline stages do not split yet."""
     from repro.models.transformer import n_periods
 
     np_ = n_periods(cfg)
@@ -55,7 +57,7 @@ def feasible_configs(
             continue
         rest = world // tp
         for pp in _divisors(rest):
-            if pp > max_pp or np_ % pp != 0:
+            if pp > max_pp or np_ % pp != 0 or (pp > 1 and cfg.dense_layers):
                 continue
             dp = rest // pp
             if global_batch % dp != 0:

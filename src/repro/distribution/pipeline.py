@@ -71,6 +71,9 @@ def make_pipeline_loss(
 ):
     """Loss over a pipelined forward — an ordinary differentiable function
     (GSPMD handles all placement through sharding constraints)."""
+    if cfg.dense_layers:
+        raise NotImplementedError(f"{cfg.name}: pipeline stages split one periodic layer "
+                                  "stack, not a stack of leading dense layers before it")
     prog = block_program(cfg)
     np_ = n_periods(cfg)
     pp = parallel.pp
@@ -113,7 +116,7 @@ def make_pipeline_loss(
             def body(carry, period_params):
                 h = carry
                 for j, (mixer, mlp) in enumerate(prog):
-                    h, _, _ = _block_apply_full(
+                    h, *_ = _block_apply_full(
                         period_params[f"pos{j}"], cfg, mixer, mlp, h, positions, True
                     )
                 return h, None
@@ -142,7 +145,7 @@ def make_pipeline_loss(
             # head matmul runs per stage slice (one per pipe group — the
             # same unconditional-compute-then-mask pattern a lax.cond would
             # break by hiding the TP collective from non-last stages.
-            h = L.rmsnorm_apply(params["final_norm"], y)
+            h = L.rmsnorm_apply(params["final_norm"], y, cfg.norm_eps)
             logits = L.lm_head_apply(
                 params.get("lm_head"), params["embed"], h
             ).astype(jnp.float32)

@@ -59,8 +59,8 @@ def _project_qkv(params, cfg: ModelConfig, x, positions, rope: bool = True):
     kk = kk.reshape(b, s, k, hd)
     v = v.reshape(b, s, k, hd)
     if cfg.qk_norm and "q_norm" in params:
-        q = head_rmsnorm_apply(params["q_norm"], q)
-        kk = head_rmsnorm_apply(params["k_norm"], kk)
+        q = head_rmsnorm_apply(params["q_norm"], q, cfg.norm_eps)
+        kk = head_rmsnorm_apply(params["k_norm"], kk, cfg.norm_eps)
     if rope:
         q = apply_rope(q, positions, cfg.rope_theta)
         kk = apply_rope(kk, positions, cfg.rope_theta)

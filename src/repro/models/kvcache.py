@@ -13,7 +13,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import ssm as ssm_mod
-from repro.models.transformer import block_program, n_periods
+from repro.models.transformer import block_program, n_periods, require_one_stack
 
 
 def cache_capacity(cfg: ModelConfig, max_seq: int) -> int:
@@ -24,6 +24,7 @@ def cache_capacity(cfg: ModelConfig, max_seq: int) -> int:
 
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype=jnp.bfloat16):
     """Zero cache pytree (real or under jax.eval_shape for abstract)."""
+    require_one_stack(cfg, "the decode cache")
     prog = block_program(cfg)
     np_ = n_periods(cfg)
     kh, hd = cfg.num_kv_heads, cfg.resolved_head_dim

@@ -37,7 +37,7 @@ def rmsnorm_init(d: int, dtype) -> tuple[dict, dict]:
     return {"scale": jnp.ones((d,), dtype)}, {"scale": ("embed",)}
 
 
-def rmsnorm_apply(params: dict, x: jax.Array, eps: float = 1e-6) -> jax.Array:
+def rmsnorm_apply(params: dict, x: jax.Array, eps: float) -> jax.Array:
     dtype = x.dtype
     xf = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
@@ -45,7 +45,7 @@ def rmsnorm_apply(params: dict, x: jax.Array, eps: float = 1e-6) -> jax.Array:
     return (y * params["scale"].astype(jnp.float32)).astype(dtype)
 
 
-def head_rmsnorm_apply(scale: jax.Array, x: jax.Array, eps: float = 1e-6) -> jax.Array:
+def head_rmsnorm_apply(scale: jax.Array, x: jax.Array, eps: float) -> jax.Array:
     """qk-norm: normalize over the trailing head_dim."""
     dtype = x.dtype
     xf = x.astype(jnp.float32)

@@ -145,7 +145,28 @@ def encode(cfg: ModelConfig, params, frames: jax.Array, remat: str = "full"):
         causal=False,
         remat=remat,
     )
-    return L.rmsnorm_apply(params["encoder"]["final_norm"], x)
+    return L.rmsnorm_apply(params["encoder"]["final_norm"], x, cfg.norm_eps)
+
+
+def _forward(cfg: ModelConfig, params: dict, batch: dict, remat: str):
+    """(logits, the layer stack's sums: ``T.stack_forward``)."""
+    adt = _dtype(cfg.dtype)
+    tokens = batch["tokens"]
+    b, s = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
+    enc_out = None
+    if cfg.family == "encdec":
+        enc_out = encode(cfg, params, batch["frames"], remat=remat)
+    with jax.named_scope("embed"):
+        x = shard_hints.constrain(L.embed_apply(params["embed"], tokens, adt), "activation")
+    x, sums = T.stack_forward(
+        params["blocks"], cfg, x, positions, causal=True, enc_out=enc_out, remat=remat
+    )
+    with jax.named_scope("head"):
+        x = L.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
+        logits = L.lm_head_apply(params.get("lm_head"), params["embed"], x)
+        logits = shard_hints.constrain(logits, "logits")
+    return logits, sums
 
 
 def forward(
@@ -156,30 +177,19 @@ def forward(
     batch: {"tokens": (b,s) int32} for decoder-only;
            {"frames": (b,s_enc,d), "tokens": (b,s) int32} for enc-dec.
     """
-    adt = _dtype(cfg.dtype)
-    tokens = batch["tokens"]
-    b, s = tokens.shape
-    positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
-    enc_out = None
-    if cfg.family == "encdec":
-        enc_out = encode(cfg, params, batch["frames"], remat=remat)
-    with jax.named_scope("embed"):
-        x = shard_hints.constrain(L.embed_apply(params["embed"], tokens, adt), "activation")
-    x, aux = T.stack_forward(
-        params["blocks"], cfg, x, positions, causal=True, enc_out=enc_out, remat=remat
-    )
-    with jax.named_scope("head"):
-        x = L.rmsnorm_apply(params["final_norm"], x)
-        logits = L.lm_head_apply(params.get("lm_head"), params["embed"], x)
-        logits = shard_hints.constrain(logits, "logits")
-    return logits, aux
+    logits, sums = _forward(cfg, params, batch, remat)
+    return logits, sums["moe_aux"]
 
 
 def loss_fn(
     cfg: ModelConfig, params: dict, batch: dict, remat: str = "full",
     aux_weight: float = 0.01,
 ) -> tuple[jax.Array, dict]:
-    logits, aux = forward(cfg, params, batch, remat=remat)
+    """(loss, metrics): ``nll``, ``moe_aux`` and, for a model with MoE
+    layers, ``moe_local_rows``: the (token, choice) pairs routed to the
+    experts this world holds, summed over the layers."""
+    logits, sums = _forward(cfg, params, batch, remat)
+    aux = sums["moe_aux"]
     tokens = batch["tokens"]
     with jax.named_scope("head"):
         logits = logits[:, :-1].astype(jnp.float32)
@@ -188,7 +198,7 @@ def loss_fn(
         tgt_logit = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
         nll = (logz - tgt_logit).mean()
     loss = nll + aux_weight * aux
-    return loss, {"nll": nll, "moe_aux": aux}
+    return loss, {"nll": nll, **sums}
 
 
 # ---------------------------------------------------------------------------
@@ -226,7 +236,7 @@ def prefill(
         cross_kv = _build_cross_kv(cfg, params, enc_out)
     x = shard_hints.constrain(L.embed_apply(params["embed"], tokens, adt), "activation")
     x, collected = T.stack_prefill(params["blocks"], cfg, x, positions, enc_out=enc_out)
-    x = L.rmsnorm_apply(params["final_norm"], x[:, -1:])
+    x = L.rmsnorm_apply(params["final_norm"], x[:, -1:], cfg.norm_eps)
     logits = L.lm_head_apply(params.get("lm_head"), params["embed"], x)
     cache = kvcache.cache_from_prefill(cfg, collected, cache_dtype, max_seq=max_seq)
     return logits, cache, cross_kv
@@ -261,7 +271,7 @@ def prefill_chunked(
         x = L.embed_apply(params["embed"], chunk, adt)
         x, cache = T.stack_chunk(params["blocks"], cfg, x, cache, pos0)
         x_last = x
-    h = L.rmsnorm_apply(params["final_norm"], x_last[:, -1:])
+    h = L.rmsnorm_apply(params["final_norm"], x_last[:, -1:], cfg.norm_eps)
     logits = L.lm_head_apply(params.get("lm_head"), params["embed"], h)
     return logits, cache
 
@@ -294,7 +304,7 @@ def decode_step(
     adt = _dtype(cfg.dtype)
     x = L.embed_apply(params["embed"], tokens, adt)
     x, new_cache = T.stack_decode(params["blocks"], cfg, x, cache, pos, cross_kv)
-    x = L.rmsnorm_apply(params["final_norm"], x)
+    x = L.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
     logits = L.lm_head_apply(params.get("lm_head"), params["embed"], x)
     return logits, new_cache
 

@@ -1,9 +1,22 @@
-"""Mixture-of-Experts MLP with capacity-based GShard-style dispatch.
+"""Mixture-of-Experts MLP with dropless, sort-based dispatch.
+
+The router scores every expert of the model (``num_experts``); the layer
+computes the part of the result that its own experts give: the
+``held_experts`` from ``first_expert`` on, as one rank of an expert-parallel
+deployment holds them. Each (token, choice) pair routed to a held expert is
+computed, however many go to one expert: the pairs are sorted by expert and
+one grouped matrix product (``jax.lax.ragged_dot``) runs each expert over
+its own rows. Pairs routed to experts held elsewhere sort last, fall outside
+every group and add nothing here: their rows are zeros in every product's
+result and input gradient (:func:`_grouped`).
 
 Expert weights are stacked on a leading ``expert`` logical axis so they are
 (a) shardable over a mesh axis (EP) and (b) first-class tensors in the
 Abstract Resource View — EP reshaping (App. A.2.3 of the paper) migrates
 slices of these tensors like any other.
+
+Scopes (``jax.named_scope``): ``moe/route``, ``moe/dispatch``,
+``moe/experts`` and ``moe/combine``.
 """
 
 from __future__ import annotations
@@ -12,15 +25,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models import shard_hints
 from repro.models.layers import _dense_init, _act, mlp_init, mlp_apply
 
 
 def moe_init(rng, cfg: ModelConfig, dtype) -> tuple[dict, dict]:
-    e, d, f = cfg.num_experts, cfg.d_model, cfg.d_ff
+    e, d, f = cfg.held_experts, cfg.d_model, cfg.resolved_expert_d_ff
     keys = jax.random.split(rng, 5)
     params = {
-        "router": _dense_init(keys[0], (d, e), dtype),
+        "router": _dense_init(keys[0], (d, cfg.num_experts), dtype),
         "wi_gate": _dense_init(keys[1], (e, d, f), dtype, in_axis=1),
         "wi_up": _dense_init(keys[2], (e, d, f), dtype, in_axis=1),
         "wo": _dense_init(keys[3], (e, f, d), dtype, in_axis=1),
@@ -31,6 +43,9 @@ def moe_init(rng, cfg: ModelConfig, dtype) -> tuple[dict, dict]:
         "wi_up": ("expert", "embed", "ffn"),
         "wo": ("expert", "ffn", "embed"),
     }
+    if cfg.router == "sigmoid_bias":
+        params["expert_bias"] = jnp.zeros((cfg.num_experts,), dtype)
+        axes["expert_bias"] = ("expert_in",)
     if cfg.moe_shared_expert:
         sp, sa = mlp_init(keys[4], cfg, dtype)
         params["shared"] = sp
@@ -38,55 +53,124 @@ def moe_init(rng, cfg: ModelConfig, dtype) -> tuple[dict, dict]:
     return params, axes
 
 
-def moe_apply(
-    params: dict,
-    cfg: ModelConfig,
-    x: jax.Array,  # (b, s, d)
-) -> jax.Array:
-    b, s, d = x.shape
-    e, k = cfg.num_experts, cfg.experts_per_token
-    capacity = max(1, int(cfg.moe_capacity_factor * k * s / e))
-
-    logits = (x @ params["router"].astype(x.dtype)).astype(jnp.float32)  # (b,s,e)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)  # (b,s,k)
-    gate_vals = gate_vals / jnp.clip(gate_vals.sum(-1, keepdims=True), 1e-9)
-
-    # position of each (token, choice) within its expert's capacity buffer
-    onehot = jax.nn.one_hot(gate_idx, e, dtype=jnp.float32)  # (b,s,k,e)
-    flat = onehot.reshape(b, s * k, e)
-    pos_in_expert = (jnp.cumsum(flat, axis=1) - flat).reshape(b, s, k, e)
-    pos = jnp.einsum("bske,bske->bsk", pos_in_expert, onehot)  # (b,s,k)
-    keep = pos < capacity
-    gate_vals = gate_vals * keep.astype(gate_vals.dtype)
-
-    # dispatch/combine tensors (b, s, e, c)
-    pos_oh = jax.nn.one_hot(pos.astype(jnp.int32), capacity, dtype=jnp.float32)
-    dispatch = jnp.einsum("bske,bskc->bsec", onehot, pos_oh * keep[..., None])
-    combine = jnp.einsum("bsk,bske,bskc->bsec", gate_vals, onehot, pos_oh)
-
-    dispatch = shard_hints.constrain(dispatch, "moe_dispatch")
-    combine = shard_hints.constrain(combine, "moe_dispatch")
-    xin = jnp.einsum("bsec,bsd->ebcd", dispatch.astype(x.dtype), x)  # (e,b,c,d)
-    xin = shard_hints.constrain(xin, "moe_expert_in")
-    gate = _act(cfg.act, jnp.einsum("ebcd,edf->ebcf", xin, params["wi_gate"].astype(x.dtype)))
-    up = jnp.einsum("ebcd,edf->ebcf", xin, params["wi_up"].astype(x.dtype))
-    gate = shard_hints.constrain(gate, "moe_expert_mid")
-    yout = jnp.einsum("ebcf,efd->ebcd", gate * up, params["wo"].astype(x.dtype))
-    yout = shard_hints.constrain(yout, "moe_expert_in")
-    y = jnp.einsum("bsec,ebcd->bsd", combine.astype(x.dtype), yout)
-
-    if cfg.moe_shared_expert:
-        y = y + mlp_apply(params["shared"], x, cfg.act)
-    return y
+def _route(params: dict, cfg: ModelConfig, logits: jax.Array):
+    """logits (..., E) f32 -> (weights (..., k), experts (..., k), aux loss)."""
+    k = cfg.experts_per_token
+    if cfg.router == "sigmoid_bias":
+        scores = jax.nn.sigmoid(logits)
+        bias = jax.lax.stop_gradient(params["expert_bias"].astype(jnp.float32))
+        _, experts = jax.lax.top_k(scores + bias, k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+        weights = weights / (weights.sum(-1, keepdims=True) + 1e-6)
+        aux = jnp.zeros((), jnp.float32)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, experts = jax.lax.top_k(probs, k)
+        weights = weights / jnp.clip(weights.sum(-1, keepdims=True), 1e-9)
+        aux = _switch_loss(probs)
+    return weights * cfg.routed_scale, experts, aux
 
 
-def moe_aux_loss(params: dict, cfg: ModelConfig, x: jax.Array) -> jax.Array:
-    """Load-balancing auxiliary loss (Switch-style)."""
-    logits = (x @ params["router"].astype(x.dtype)).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)  # (b,s,e)
-    e = cfg.num_experts
+def _switch_loss(probs: jax.Array) -> jax.Array:
+    """Load-balancing auxiliary loss (Switch-style) of router probs (b, s, e)."""
+    e = probs.shape[-1]
     top1 = jnp.argmax(probs, axis=-1)
     frac_tokens = jnp.mean(jax.nn.one_hot(top1, e, dtype=jnp.float32), axis=(0, 1))
     frac_probs = jnp.mean(probs, axis=(0, 1))
     return e * jnp.sum(frac_tokens * frac_probs)
+
+
+# The permutations are gathers both ways: the transpose of a gather is a
+# scatter-add, and the inverse permutation gathers the same rows back.
+
+
+@jax.custom_vjp
+def _dispatch_rows(x, order, inv):
+    """Row r of the result is token ``order[r] // k``'s row of x (t, d)."""
+    k = order.shape[0] // x.shape[0]
+    return x[order // k]
+
+
+def _dispatch_fwd(x, order, inv):
+    return _dispatch_rows(x, order, inv), (order, inv, x.shape[0])
+
+
+def _dispatch_bwd(res, g):
+    order, inv, t = res
+    return g[inv].reshape(t, -1, g.shape[-1]).sum(1), None, None
+
+
+_dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine_rows(y, order, inv):
+    """Pair p's row of the expert outputs y, which are in expert order."""
+    return y[inv]
+
+
+def _combine_fwd(y, order, inv):
+    return y[inv], (order, inv)
+
+
+def _combine_bwd(res, g):
+    order, inv = res
+    return g[order], None, None
+
+
+_combine_rows.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _grouped(x, w, group_sizes, grouped_rows):
+    """``jax.lax.ragged_dot(x, w, group_sizes)`` with the rows outside every
+    group zero, in the result and in x's gradient. XLA's TPU kernel leaves
+    those rows unwritten (the CPU's are zeros); masking the input as well
+    keeps what they hold out of the backward's products."""
+    def zero(a):
+        return jnp.where(grouped_rows, a, 0)
+
+    return zero(jax.lax.ragged_dot(zero(x), w, group_sizes))
+
+
+def moe_apply(
+    params: dict,
+    cfg: ModelConfig,
+    x: jax.Array,  # (b, s, d)
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Returns (y, aux_loss, local_rows): the held experts' part of the
+    layer's output, the router's auxiliary loss and the number of (token,
+    choice) pairs routed to held experts."""
+    b, s, d = x.shape
+    k, held = cfg.experts_per_token, cfg.held_experts
+    t = b * s
+    with jax.named_scope("moe"):
+        xt = x.reshape(t, d)
+        with jax.named_scope("route"):
+            logits = jnp.dot(xt, params["router"].astype(x.dtype),
+                             preferred_element_type=jnp.float32)
+            weights, experts, aux = _route(params, cfg, logits.reshape(b, s, -1))
+        with jax.named_scope("dispatch"):
+            expert = experts.reshape(t * k) - cfg.first_expert
+            local = (expert >= 0) & (expert < held)
+            # pairs of experts held elsewhere sort after every held group
+            key = jnp.where(local, expert, held)
+            order = jnp.argsort(key, stable=True)
+            inv = jnp.zeros_like(order).at[order].set(
+                jnp.arange(t * k, dtype=order.dtype))
+            group_sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+            xs = _dispatch_rows(xt, order, inv)
+        with jax.named_scope("experts"):
+            grouped = (jnp.arange(t * k) < group_sizes.sum())[:, None]
+            gate, up = (_grouped(xs, params[w].astype(x.dtype), group_sizes, grouped)
+                        for w in ("wi_gate", "wi_up"))
+            ys = _grouped(_act(cfg.act, gate) * up, params["wo"].astype(x.dtype),
+                          group_sizes, grouped)
+        with jax.named_scope("combine"):
+            # the rows of pairs not held are zeros: they add nothing
+            y = jnp.einsum("tk,tkd->td", weights.reshape(t, k).astype(x.dtype),
+                           _combine_rows(ys, order, inv).reshape(t, k, d),
+                           preferred_element_type=jnp.float32)
+            y = y.astype(x.dtype).reshape(b, s, d)
+    if cfg.moe_shared_expert:
+        y = y + mlp_apply(params["shared"], x, cfg.act)
+    return y, aux, group_sizes.sum()

@@ -25,9 +25,6 @@ _HINTS: contextvars.ContextVar[dict] = contextvars.ContextVar(
 #   activation      (b, s, d)       embedding output / residual stream
 #   logits          (b, s, vocab)   pre-CE logits
 #   attn_qkv        (b, s, h, hd)   q/k/v after head reshape
-#   moe_expert_in   (e, b, c, d)    dispatched expert inputs
-#   moe_expert_mid  (e, b, c, f)    expert hidden activations
-#   moe_dispatch    (b, s, e, c)    dispatch/combine one-hots
 
 
 @contextlib.contextmanager
@@ -63,22 +60,9 @@ def make_train_hints(mesh, version: str) -> dict:
             **make_train_hints(mesh, "v1"),
             "attn_qkv": ns(bspec, None, "model", None),
         }
-    if version == "v3":  # v2 + expert-parallel MoE dispatch
-        return {
-            **make_train_hints(mesh, "v2"),
-            "moe_expert_in": ns("model", bspec, None, None),
-            "moe_expert_mid": ns("model", bspec, None, None),
-            "moe_dispatch": ns(bspec, None, "model", None),
-        }
     if version == "v4":  # v2 + sequence-parallel residual stream
         return {
             **make_train_hints(mesh, "v2"),
             "activation": ns(bspec, "model", None),
-        }
-    if version == "moe_only":
-        return {
-            "moe_expert_in": ns("model", bspec, None, None),
-            "moe_expert_mid": ns("model", bspec, None, None),
-            "moe_dispatch": ns(bspec, None, "model", None),
         }
     raise KeyError(version)

@@ -65,8 +65,9 @@ def ssm_init(rng, cfg: ModelConfig, dtype) -> tuple[dict, dict]:
     return params, axes
 
 
-def _causal_conv(xbc: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
-    """Depthwise causal conv, xbc: (b, s, c), w: (K, c)."""
+def _causal_conv(xbc: jax.Array, w: jax.Array, b: jax.Array | None = None) -> jax.Array:
+    """Depthwise causal conv, xbc: (b, s, c), w: (K, c); out[t] is the sum
+    over taps i of w[i] * xbc[t - (K - 1) + i]."""
     K = w.shape[0]
     pad = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))
     out = jnp.zeros_like(xbc, dtype=jnp.float32)
@@ -74,7 +75,9 @@ def _causal_conv(xbc: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
         out = out + pad[:, t : t + xbc.shape[1], :].astype(jnp.float32) * w[t].astype(
             jnp.float32
         )
-    return (out + b.astype(jnp.float32)).astype(xbc.dtype)
+    if b is not None:
+        out = out + b.astype(jnp.float32)
+    return out.astype(xbc.dtype)
 
 
 def _gated_norm(y: jax.Array, z: jax.Array, scale: jax.Array, eps=1e-6) -> jax.Array:

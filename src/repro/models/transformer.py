@@ -5,13 +5,14 @@ periods) so that heterogeneous stacks — Jamba's 1-attention-per-8 hybrid with
 MoE every 2nd layer, Mixtral's uniform MoE, Mamba-2's MLP-free blocks — all
 scan over ``n_periods`` with per-position stacked parameters. This keeps the
 lowered HLO size independent of depth (critical for dry-run compile times)
-and gives pipeline parallelism a natural stage boundary.
+and gives pipeline parallelism a natural stage boundary. Leading dense
+layers before the MoE layers (LFM2's) are a stack of their own, scanned
+first (:func:`segments`).
 """
 
 from __future__ import annotations
 
 import contextlib
-import math
 import os
 from typing import Any
 
@@ -21,6 +22,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models import attention as attn
 from repro.models import moe as moe_mod
+from repro.models import short_conv
 from repro.models import ssm as ssm_mod
 from repro.models.layers import mlp_apply, mlp_init, rmsnorm_apply, rmsnorm_init
 
@@ -36,32 +38,80 @@ def _unroll() -> bool:
     return os.environ.get("REPRO_SCAN_UNROLL") == "1"
 
 
-def block_program(cfg: ModelConfig) -> list[tuple[str, str]]:
-    """[(mixer, mlp)] per position within one period.
+def layer_program(cfg: ModelConfig) -> list[tuple[str, str]]:
+    """[(mixer, mlp)] of every decoder layer.
 
-    mixer in {"attn", "ssm"}; mlp in {"dense", "moe", "none"}.
+    mixer in {"attn", "ssm", "conv"}; mlp in {"dense", "moe", "none"}.
     """
-    period = 1
-    if cfg.family == "hybrid" and cfg.attn_period > 0:
-        period = math.lcm(cfg.attn_period, cfg.moe_period if cfg.num_experts else 1)
-    elif cfg.num_experts > 0 and cfg.moe_period > 1:
-        period = cfg.moe_period
-    assert cfg.num_layers % period == 0, (cfg.name, cfg.num_layers, period)
     prog = []
-    for j in range(period):
-        mixer = cfg.layer_kind(j)
+    for layer in range(cfg.num_layers):
         if cfg.d_ff == 0:
             mlp = "none"
-        elif cfg.is_moe_layer(j):
+        elif cfg.is_moe_layer(layer):
             mlp = "moe"
         else:
             mlp = "dense"
-        prog.append((mixer, mlp))
+        prog.append((cfg.layer_kind(layer), mlp))
     return prog
 
 
+def _period(layers: list) -> int:
+    """The least p such that ``layers`` is p-periodic over its whole length
+    and p divides it."""
+    n = len(layers)
+    return next(p for p in range(1, n + 1)
+                if n % p == 0 and layers == layers[:p] * (n // p))
+
+
+def segments(cfg: ModelConfig) -> list[tuple[str, list[tuple[str, str]], int]]:
+    """The stacks the layers are scanned in, in order: [(prefix, program,
+    repeats)].
+
+    ``lead``: the leading dense layers (``dense_layers``), if any; ``pos``:
+    every later layer, as the least period that repeats to the end. A
+    pattern that does not repeat (LFM2's attention every fourth layer, then
+    every third) is one period of all those layers. Parameters of position
+    j of a stack sit at ``blocks/<prefix><j>``, stacked over its repeats.
+    """
+    layers = layer_program(cfg)
+    lead, rest = layers[: cfg.dense_layers], layers[cfg.dense_layers :]
+    out = []
+    if lead:
+        p = _period(lead)
+        out.append(("lead", lead[:p], len(lead) // p))
+    p = _period(rest)
+    return out + [("pos", rest[:p], len(rest) // p)]
+
+
+def layer_stacks(cfg: ModelConfig) -> dict[str, tuple[int, int]]:
+    """{prefix: (first layer, period)} of each stack of :func:`segments`:
+    the global layer of position j in repeat r of a stack is
+    first + r * period + j."""
+    out, first = {}, 0
+    for prefix, prog, repeats in segments(cfg):
+        out[prefix] = (first, len(prog))
+        first += len(prog) * repeats
+    return out
+
+
+def block_program(cfg: ModelConfig) -> list[tuple[str, str]]:
+    """[(mixer, mlp)] per position of the main periodic stack (``pos``)."""
+    return next(prog for prefix, prog, _ in segments(cfg) if prefix == "pos")
+
+
 def n_periods(cfg: ModelConfig) -> int:
-    return cfg.num_layers // len(block_program(cfg))
+    """Repeats of the main periodic stack."""
+    return next(n for prefix, _, n in segments(cfg) if prefix == "pos")
+
+
+def require_one_stack(cfg: ModelConfig, what: str) -> None:
+    """Raise ``NotImplementedError`` where ``what`` (serving, pipeline stages)
+    only knows one periodic stack of attention and SSM layers."""
+    if any(mixer == "conv" for mixer, _ in layer_program(cfg)):
+        raise NotImplementedError(f"{cfg.name}: {what} has no short-conv ('conv') layers yet")
+    if cfg.dense_layers:
+        raise NotImplementedError(f"{cfg.name}: {what} runs one periodic layer stack, "
+                                  "not a stack of leading dense layers before it")
 
 
 # ---------------------------------------------------------------------------
@@ -76,6 +126,8 @@ def _block_init(rng, cfg: ModelConfig, mixer: str, mlp: str, dtype, cross: bool)
     params["ln1"], axes["ln1"] = rmsnorm_init(cfg.d_model, dtype)
     if mixer == "attn":
         params["mixer"], axes["mixer"] = attn.attn_init(keys[0], cfg, dtype)
+    elif mixer == "conv":
+        params["mixer"], axes["mixer"] = short_conv.short_conv_init(keys[0], cfg, dtype)
     else:
         params["mixer"], axes["mixer"] = ssm_mod.ssm_init(keys[0], cfg, dtype)
     if cross:
@@ -90,6 +142,10 @@ def _block_init(rng, cfg: ModelConfig, mixer: str, mlp: str, dtype, cross: bool)
     return params, axes
 
 
+# the benchmark's trace reader sums device time by these scopes
+_MIXER_SCOPE = {"attn": "attn", "conv": "short_conv"}
+
+
 def _block_apply_full(
     bp: dict,
     cfg: ModelConfig,
@@ -101,13 +157,15 @@ def _block_apply_full(
     enc_out: jax.Array | None = None,
     collect_kv: bool = False,
 ):
-    """Returns (x, aux_loss, kv) — kv is (k, v) when collect_kv and attn."""
+    """Returns (x, aux_loss, local_rows, kv): local_rows is the MoE layer's
+    pairs routed to held experts (None for other layers); kv is (k, v) when
+    collect_kv and attn."""
     aux = jnp.zeros((), jnp.float32)
+    rows = None
     kv = None
-    # attention and MLP ops carry "attn" / "mlp" in their op_name, which
-    # the benchmark's trace reader sums by layer
-    with jax.named_scope("attn") if mixer == "attn" else contextlib.nullcontext():
-        h = rmsnorm_apply(bp["ln1"], x)
+    scope = _MIXER_SCOPE.get(mixer)
+    with jax.named_scope(scope) if scope else contextlib.nullcontext():
+        h = rmsnorm_apply(bp["ln1"], x, cfg.norm_eps)
         if mixer == "attn":
             if collect_kv:
                 out, k, v = attn.attn_forward(
@@ -117,6 +175,8 @@ def _block_apply_full(
             else:
                 out = attn.attn_forward(bp["mixer"], cfg, h, positions, causal=causal)
             h = out
+        elif mixer == "conv":
+            h = short_conv.short_conv_apply(bp["mixer"], h)
         else:
             if collect_kv:
                 h, state = ssm_mod.ssm_forward(bp["mixer"], cfg, h, return_state=True)
@@ -125,14 +185,14 @@ def _block_apply_full(
                 h = ssm_mod.ssm_forward(bp["mixer"], cfg, h)
     x = x + h
     if enc_out is not None and "cross" in bp:
-        h = rmsnorm_apply(bp["ln_x"], x)
+        h = rmsnorm_apply(bp["ln_x"], x, cfg.norm_eps)
         x = x + attn.cross_attn_forward(bp["cross"], cfg, h, enc_out)
     if mlp != "none":
         with jax.named_scope("mlp"):
-            h = rmsnorm_apply(bp["ln2"], x)
+            h = rmsnorm_apply(bp["ln2"], x, cfg.norm_eps)
             if mlp == "moe":
-                aux = aux + moe_mod.moe_aux_loss(bp["mlp"], cfg, h)
-                h = moe_mod.moe_apply(bp["mlp"], cfg, h)
+                h, a, rows = moe_mod.moe_apply(bp["mlp"], cfg, h)
+                aux = aux + a
             else:
                 h = mlp_apply(bp["mlp"], h, cfg.act)
         x = x + h
@@ -142,29 +202,30 @@ def _block_apply_full(
     from repro.models import shard_hints
 
     x = shard_hints.constrain(x, "activation")
-    return x, aux, kv
+    return x, aux, rows, kv
 
 
 # ---------------------------------------------------------------------------
-# Stack init (stacked over n_periods) and forward scan
+# Stack init (each segment stacked over its repeats) and forward scan
 # ---------------------------------------------------------------------------
 
 
 def stack_init(rng, cfg: ModelConfig, dtype, cross: bool = False):
-    prog = block_program(cfg)
-    np_ = n_periods(cfg)
     params, axes = {}, {}
-    rngs = jax.random.split(rng, len(prog))
-    for j, (mixer, mlp) in enumerate(prog):
-        keys = jax.random.split(rngs[j], np_)
-        stacked = jax.vmap(
-            lambda k: _block_init(k, cfg, mixer, mlp, dtype, cross)[0]
-        )(keys)
-        _, ax = _block_init(rngs[j], cfg, mixer, mlp, dtype, cross)
-        params[f"pos{j}"] = stacked
-        axes[f"pos{j}"] = jax.tree_util.tree_map(
-            lambda a: ("layers",) + a, ax, is_leaf=lambda a: isinstance(a, tuple)
-        )
+    segs = segments(cfg)
+    seg_rngs = jax.random.split(rng, len(segs)) if len(segs) > 1 else [rng]
+    for seg_rng, (prefix, prog, repeats) in zip(seg_rngs, segs):
+        rngs = jax.random.split(seg_rng, len(prog))
+        for j, (mixer, mlp) in enumerate(prog):
+            keys = jax.random.split(rngs[j], repeats)
+            stacked = jax.vmap(
+                lambda k: _block_init(k, cfg, mixer, mlp, dtype, cross)[0]
+            )(keys)
+            _, ax = _block_init(rngs[j], cfg, mixer, mlp, dtype, cross)
+            params[f"{prefix}{j}"] = stacked
+            axes[f"{prefix}{j}"] = jax.tree_util.tree_map(
+                lambda a: ("layers",) + a, ax, is_leaf=lambda a: isinstance(a, tuple)
+            )
     return params, axes
 
 
@@ -176,29 +237,36 @@ def stack_forward(
     causal: bool = True,
     enc_out: jax.Array | None = None,
     remat: str = "full",
-) -> tuple[jax.Array, jax.Array]:
-    """Returns (x, total_moe_aux_loss)."""
-    prog = block_program(cfg)
+) -> tuple[jax.Array, dict]:
+    """Returns (x, sums): ``moe_aux``, the MoE layers' auxiliary losses, and
+    where the model has MoE layers ``moe_local_rows``, their pairs routed to
+    held experts."""
+    sums = {"moe_aux": jnp.zeros((), jnp.float32)}
+    if any(mlp == "moe" for _, mlp in layer_program(cfg)):
+        sums["moe_local_rows"] = jnp.zeros((), jnp.int32)
+    for prefix, prog, _ in segments(cfg):
 
-    def body(carry, period_params):
-        h, aux = carry
-        for j, (mixer, mlp) in enumerate(prog):
-            h, a, _ = _block_apply_full(
-                period_params[f"pos{j}"], cfg, mixer, mlp, h, positions, causal, enc_out
+        def body(carry, period_params, prefix=prefix, prog=prog):
+            h, sums = carry
+            for j, (mixer, mlp) in enumerate(prog):
+                h, a, rows, _ = _block_apply_full(
+                    period_params[f"{prefix}{j}"], cfg, mixer, mlp, h, positions,
+                    causal, enc_out,
+                )
+                sums = dict(sums, moe_aux=sums["moe_aux"] + a)
+                if rows is not None:
+                    sums["moe_local_rows"] = sums["moe_local_rows"] + rows
+            return (h, sums), None
+
+        if remat == "full":
+            body = jax.checkpoint(body)
+        elif remat == "dots":
+            body = jax.checkpoint(
+                body, policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims
             )
-            aux = aux + a
-        return (h, aux), None
-
-    if remat == "full":
-        body = jax.checkpoint(body)
-    elif remat == "dots":
-        body = jax.checkpoint(
-            body, policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims
-        )
-    (x, aux), _ = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), params, unroll=_unroll()
-    )
-    return x, aux
+        stack = {f"{prefix}{j}": params[f"{prefix}{j}"] for j in range(len(prog))}
+        (x, sums), _ = jax.lax.scan(body, (x, sums), stack, unroll=_unroll())
+    return x, sums
 
 
 def stack_prefill(
@@ -215,13 +283,14 @@ def stack_prefill(
     For sliding-window attention the collected KV is cropped to the ring
     window by the caller (kvcache.cache_from_prefill).
     """
+    require_one_stack(cfg, "prefill")
     prog = block_program(cfg)
 
     def body(carry, period_params):
         h = carry
         ys = {}
         for j, (mixer, mlp) in enumerate(prog):
-            h, _, kv = _block_apply_full(
+            h, _, _, kv = _block_apply_full(
                 period_params[f"pos{j}"],
                 cfg,
                 mixer,
@@ -252,6 +321,7 @@ def stack_chunk(
     """Chunked-prefill step: like stack_decode but for C tokens at once —
     bounds prefill activation memory to O(C·context) instead of O(s²)
     (decoder-only archs; see model.prefill_chunked)."""
+    require_one_stack(cfg, "chunked prefill")
     prog = block_program(cfg)
 
     def body(carry, xs):
@@ -261,7 +331,7 @@ def stack_chunk(
         for j, (mixer, mlp) in enumerate(prog):
             bp = period_params[f"pos{j}"]
             c = period_cache[f"pos{j}"]
-            hin = rmsnorm_apply(bp["ln1"], h)
+            hin = rmsnorm_apply(bp["ln1"], h, cfg.norm_eps)
             if mixer == "attn":
                 out, ck, cv = attn.attn_chunk(
                     bp["mixer"], cfg, hin, c["k"], c["v"], pos0
@@ -277,9 +347,9 @@ def stack_chunk(
                 }
             h = h + out
             if mlp != "none":
-                h2 = rmsnorm_apply(bp["ln2"], h)
+                h2 = rmsnorm_apply(bp["ln2"], h, cfg.norm_eps)
                 if mlp == "moe":
-                    h2 = moe_mod.moe_apply(bp["mlp"], cfg, h2)
+                    h2, _, _ = moe_mod.moe_apply(bp["mlp"], cfg, h2)
                 else:
                     h2 = mlp_apply(bp["mlp"], h2, cfg.act)
                 h = h + h2
@@ -302,6 +372,7 @@ def stack_decode(
     pos: jax.Array,  # scalar int32
     cross_kv: dict | None = None,  # per-position stacked (k, v) for enc-dec
 ):
+    require_one_stack(cfg, "decode")
     prog = block_program(cfg)
 
     def body(carry, xs):
@@ -311,7 +382,7 @@ def stack_decode(
         for j, (mixer, mlp) in enumerate(prog):
             bp = period_params[f"pos{j}"]
             c = period_cache[f"pos{j}"]
-            hin = rmsnorm_apply(bp["ln1"], h)
+            hin = rmsnorm_apply(bp["ln1"], h, cfg.norm_eps)
             if mixer == "attn":
                 out, ck, cv = attn.attn_decode(bp["mixer"], cfg, hin, c["k"], c["v"], pos)
                 new_cache[f"pos{j}"] = {"k": ck, "v": cv}
@@ -320,13 +391,13 @@ def stack_decode(
                 new_cache[f"pos{j}"] = st
             h = h + out
             if period_cross is not None and "cross" in bp:
-                hx = rmsnorm_apply(bp["ln_x"], h)
+                hx = rmsnorm_apply(bp["ln_x"], h, cfg.norm_eps)
                 kv = (period_cross[f"pos{j}"]["k"], period_cross[f"pos{j}"]["v"])
                 h = h + attn.cross_attn_forward(bp["cross"], cfg, hx, kv)
             if mlp != "none":
-                h2 = rmsnorm_apply(bp["ln2"], h)
+                h2 = rmsnorm_apply(bp["ln2"], h, cfg.norm_eps)
                 if mlp == "moe":
-                    h2 = moe_mod.moe_apply(bp["mlp"], cfg, h2)
+                    h2, _, _ = moe_mod.moe_apply(bp["mlp"], cfg, h2)
                 else:
                     h2 = mlp_apply(bp["mlp"], h2, cfg.act)
                 h = h + h2

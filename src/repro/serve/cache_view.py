@@ -174,7 +174,7 @@ def serve_plan(
 ) -> TransferPlan:
     """Intersection plan for a serving resize — one plan covers params and
     cache together, so both stream through one engine pass at commit."""
-    from repro.models.transformer import block_program
+    from repro.models.transformer import layer_stacks
 
     return plan_transfer(
         specs,
@@ -182,7 +182,7 @@ def serve_plan(
         cfg_dst,
         source_policy="nearest",
         layer_granular=True,
-        num_positions=len(block_program(cfg)),
+        stacks=layer_stacks(cfg),
         allowed_src=allowed_src,
     )
 
