@@ -7,11 +7,21 @@ dims are a full ``(block, head_dim)`` tile, which the TPU's (8, 128) block
 rule accepts for any head_dim. The public entry point keeps the model's
 ``(b, s, h, d)`` layout and transposes around the kernels.
 
-Forward grid: ``(batch, q_heads, num_q_blocks, num_k_blocks)`` with the
-k-block dimension innermost ("arbitrary" semantics) so the VMEM scratch
+Tiling, shared by the forward and the backward: blocks follow the shape
+(:func:`_block`: the largest candidate that divides the length, up to 1024
+rows in the forward and 512 in the backward), a dead
+(q block, k block) pair skips its compute and its index map clamps to a
+live neighbour, so it costs no DMA, and only pairs that straddle the causal
+diagonal or the window's edge build a mask.
+
+Forward grid: ``(batch, q_heads, q blocks, k blocks)`` with the k-block
+dimension innermost ("arbitrary" semantics) so the VMEM scratch
 accumulators (running max / denominator / output block) persist across the
-online-softmax reduction — the canonical TPU flash pattern. k blocks that
-the causal/window mask removes entirely are skipped.
+online-softmax reduction — the canonical TPU flash pattern. A q block's k/v
+index map clamps into the span of k blocks its queries see
+(:func:`_k_span`): a causally dead or out-of-window step re-addresses the
+block already in VMEM. Its one output, of q's shape and dtype, is the
+signature by which the benchmark finds the forward.
 
 Backward (:data:`BACKWARD`), all under the ``flash_bwd`` scope, from the
 residuals (q, k, v, o):
@@ -26,12 +36,8 @@ residuals (q, k, v, o):
 Both kernels recompute ``p = exp(s − lse)`` a block at a time and work in
 the transposed orientation ``(k, q)``, so lse and D broadcast as rows.
 Matmul operands keep the input dtype, p and dS are cast to it, and every
-product accumulates in f32. Block sizes follow the shape
-(:func:`_bwd_block`); a dead block pair skips its compute and its index map
-clamps to a live neighbour, so it costs no DMA, and only block pairs that
-straddle the diagonal or the window's edge build a mask. dQ is written in
-f32 and dK/dV as one two-output call: no backward call has a single bf16
-output of q's shape, the signature by which the benchmark finds the forward.
+product accumulates in f32. dQ is written in f32 and dK/dV as one
+two-output call: no backward call has a single bf16 output of q's shape.
 
 The oracle is :func:`repro.kernels.ref.flash_attention_ref`.
 """
@@ -49,123 +55,22 @@ NEG_INF = -1e30
 BACKWARD = "Pallas flash backward (lse pass, dK/dV and dQ kernels)"
 
 
-def _fa_kernel(
-    q_ref,  # (1, 1, block_q, d)
-    k_ref,  # (1, 1, block_k, d)
-    v_ref,  # (1, 1, block_k, d)
-    o_ref,  # (1, 1, block_q, d)
-    m_scr,  # (block_q, 1) f32 scratch
-    l_scr,  # (block_q, 1) f32 scratch
-    acc_scr,  # (block_q, d) f32 scratch
-    *,
-    scale: float,
-    causal: bool,
-    window: int,
-    block_q: int,
-    block_k: int,
-    q_offset: int,
-):
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
-    nk = pl.num_programs(3)
-
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    q_first = qi * block_q + q_offset
-    k_first = ki * block_k
-    live = True
-    if causal:
-        live = k_first <= q_first + block_q - 1
-    if window > 0:
-        live = jnp.logical_and(live, k_first + block_k - 1 > q_first - window)
-
-    @pl.when(live)
-    def _step():
-        q = q_ref[0, 0]  # (bq, d)
-        k = k_ref[0, 0]  # (bk, d)
-        v = v_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # (bq, bk)
-        qpos = q_first + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        kpos = k_first + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        mask = jnp.ones((block_q, block_k), jnp.bool_)
-        if causal:
-            mask &= kpos <= qpos
-        if window > 0:
-            mask &= kpos > qpos - window
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_scr[...]  # (bq, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)  # (bq, bk)
-        alpha = jnp.exp(m_prev - m_new)  # (bq, 1)
-        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[...] = m_new
-
-    @pl.when(ki == nk - 1)
-    def _flush():
-        denom = jnp.where(l_scr[...] == 0.0, 1.0, l_scr[...])
-        o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
-
-
-def _fa_forward(q, k, v, causal, window, scale, block_q, block_k, interpret):
-    """Heads-major kernel call: q (b, h, s, d), k/v (b, kh, t, d)."""
-    b, h, s, d = q.shape
-    kh, t = k.shape[1], k.shape[2]
-    rep = h // kh
-    grid = (b, h, s // block_q, t // block_k)
-    kernel = functools.partial(
-        _fa_kernel,
-        scale=scale,
-        causal=causal,
-        window=window,
-        block_q=block_q,
-        block_k=block_k,
-        q_offset=t - s,  # right-aligned queries (prefill continuation)
-    )
-    kv_spec = pl.BlockSpec(
-        (1, 1, block_k, d), lambda bi, hi, qi, ki: (bi, hi // rep, ki, 0)
-    )
-    q_spec = pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0))
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(q, k, v)
-
-
 # ---------------------------------------------------------------------------
-# backward: an lse pass, then dK/dV and dQ kernels that recompute p from it
+# tiling shared by the forward and the backward
 # ---------------------------------------------------------------------------
 
-_BWD_BLOCKS = (512, 256, 128)
+_BLOCKS = (1024, 512, 256, 128)
+# the largest block of each direction, chosen by timing on a TPU v5e at the
+# benchmark cells' shapes: the forward gains up to 1024, the backward keeps
+# the 512 it was measured at
+_FWD_BLOCK, _BWD_BLOCK = 1024, 512
 _NT = (((1,), (1,)), ((), ()))  # a · bᵀ
 
 
-def _bwd_block(n: int) -> int:
-    """The largest candidate block that divides ``n``; ``n`` itself (one
-    full-length block) when none does."""
-    return next((c for c in _BWD_BLOCKS if n % c == 0), n)
+def _block(n: int, largest: int) -> int:
+    """The largest candidate block up to ``largest`` that divides ``n``;
+    ``n`` itself (one full-length block) when none does."""
+    return next((c for c in _BLOCKS if c <= largest and n % c == 0), n)
 
 
 def _k_span(q_first, bq, bk, nk, causal, window):
@@ -206,20 +111,17 @@ def _needs_mask(q_first, k_first, bq, bk, causal, window):
     return cut
 
 
-def _scores_t(q, k, q_first, k_first, masked, *, scale, causal, window):
-    """sᵀ = k qᵀ · scale, (bk, bq) in f32, masked entries at NEG_INF."""
-    st = jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32) * scale
-    if masked:
-        shape = st.shape
-        kpos = k_first + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-        qpos = q_first + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-        keep = jnp.ones(shape, jnp.bool_)
-        if causal:
-            keep &= kpos <= qpos
-        if window > 0:
-            keep &= kpos > qpos - window
-        st = jnp.where(keep, st, NEG_INF)
-    return st
+def _masked(x, q_first, k_first, q_axis, *, causal, window):
+    """Scores ``x`` of a block pair, queries along ``q_axis``, with the pairs
+    the causal/window mask removes set to NEG_INF."""
+    qpos = q_first + jax.lax.broadcasted_iota(jnp.int32, x.shape, q_axis)
+    kpos = k_first + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1 - q_axis)
+    keep = jnp.ones(x.shape, jnp.bool_)
+    if causal:
+        keep &= kpos <= qpos
+    if window > 0:
+        keep &= kpos > qpos - window
+    return jnp.where(keep, x, NEG_INF)
 
 
 def _live_blocks(live, cut, block):
@@ -227,6 +129,113 @@ def _live_blocks(live, cut, block):
     pair straddles the causal diagonal or the window's edge."""
     pl.when(jnp.logical_and(live, cut))(functools.partial(block, True))
     pl.when(jnp.logical_and(live, jnp.logical_not(cut)))(functools.partial(block, False))
+
+
+_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+)
+
+
+def _k_inner_specs(q, k, causal, window, block_q, block_k):
+    """Block specs of a ``(b, h, q blocks, k blocks)`` grid: q-shaped, k-shaped
+    (a q block's dead k blocks clamped to its span) and lse-row blocks."""
+    d, rep = q.shape[3], q.shape[1] // k.shape[1]
+    nk, q_offset = k.shape[2] // block_k, k.shape[2] - q.shape[2]
+
+    def k_block(bi, hi, qi, ki):
+        lo, top = _k_span(qi * block_q + q_offset, block_q, block_k, nk, causal, window)
+        return (bi, hi // rep, _clamp(ki, lo, top, nk), 0)
+
+    return (
+        pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+        pl.BlockSpec((1, 1, block_k, d), k_block),
+        pl.BlockSpec((1, 1, 1, block_q), lambda bi, hi, qi, ki: (bi, hi, 0, qi)),
+    )
+
+
+def _static(q, k, causal, window, scale, block_q, block_k):
+    # q_offset: queries are right-aligned to the keys (chunked prefill: t > s)
+    return dict(scale=scale, causal=causal, window=window, bq=block_q, bk=block_k,
+                q_offset=k.shape[2] - q.shape[2])
+
+
+# ---------------------------------------------------------------------------
+# forward
+# ---------------------------------------------------------------------------
+
+
+def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *, scale, causal,
+               window, bq, bk, q_offset):
+    qi, ki, nk = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
+
+    @pl.when(ki == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    q_first, k_first = qi * bq + q_offset, ki * bk
+    lo, hi = _k_span(q_first, bq, bk, nk, causal, window)
+
+    def block(masked):
+        v = v_ref[0, 0]
+        s = jax.lax.dot_general(
+            q_ref[0, 0], k_ref[0, 0], _NT, preferred_element_type=jnp.float32
+        ) * scale  # (bq, bk)
+        if masked:
+            s = _masked(s, q_first, k_first, 0, causal=causal, window=window)
+        m_prev = m_scr[...]  # (bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32
+        )
+        m_scr[...] = m_new
+
+    live = jnp.logical_and(ki >= lo, ki <= hi)
+    _live_blocks(live, _needs_mask(q_first, k_first, bq, bk, causal, window), block)
+
+    @pl.when(ki == nk - 1)
+    def _flush():
+        denom = jnp.where(l_scr[...] == 0.0, 1.0, l_scr[...])
+        o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
+
+
+def _fa_forward(q, k, v, causal, window, scale, block_q, block_k, interpret):
+    """Heads-major kernel call: q (b, h, s, d), k/v (b, kh, t, d) -> the
+    output in q's shape and dtype."""
+    b, h, s, d = q.shape
+    q_spec, kv_spec, _ = _k_inner_specs(q, k, causal, window, block_q, block_k)
+    f32 = jnp.float32
+    return pl.pallas_call(
+        functools.partial(_fa_kernel, **_static(q, k, causal, window, scale, block_q, block_k)),
+        grid=(b, h, s // block_q, k.shape[2] // block_k),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((block_q, 1), f32),
+            pltpu.VMEM((block_q, 1), f32),
+            pltpu.VMEM((block_q, d), f32),
+        ],
+        compiler_params=_PARAMS,
+        interpret=interpret,
+    )(q, k, v)
+
+
+# ---------------------------------------------------------------------------
+# backward: an lse pass, then dK/dV and dQ kernels that recompute p from it
+# ---------------------------------------------------------------------------
+
+
+def _scores_t(q, k, q_first, k_first, masked, *, scale, causal, window):
+    """sᵀ = k qᵀ · scale, (bk, bq) in f32, masked entries at NEG_INF."""
+    st = jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32) * scale
+    if masked:
+        st = _masked(st, q_first, k_first, 1, causal=causal, window=window)
+    return st
 
 
 def _lse_kernel(q_ref, k_ref, lse_ref, m_scr, l_scr, *, scale, causal, window,
@@ -324,33 +333,6 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dq_scr, *,
         dq_ref[0, 0] = dq_scr[...] * scale
 
 
-_BWD_PARAMS = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-)
-
-
-def _k_inner_specs(q, k, causal, window, block_q, block_k):
-    """Block specs of a ``(b, h, q blocks, k blocks)`` grid: q-shaped, k-shaped
-    (a q block's dead k blocks clamped to its span) and lse-row blocks."""
-    d, rep = q.shape[3], q.shape[1] // k.shape[1]
-    nk, q_offset = k.shape[2] // block_k, k.shape[2] - q.shape[2]
-
-    def k_block(bi, hi, qi, ki):
-        lo, top = _k_span(qi * block_q + q_offset, block_q, block_k, nk, causal, window)
-        return (bi, hi // rep, _clamp(ki, lo, top, nk), 0)
-
-    return (
-        pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-        pl.BlockSpec((1, 1, block_k, d), k_block),
-        pl.BlockSpec((1, 1, 1, block_q), lambda bi, hi, qi, ki: (bi, hi, 0, qi)),
-    )
-
-
-def _static(q, k, causal, window, scale, block_q, block_k):
-    return dict(scale=scale, causal=causal, window=window, bq=block_q, bk=block_k,
-                q_offset=k.shape[2] - q.shape[2])
-
-
 def _lse_pass(q, k, causal, window, scale, block_q, block_k, interpret):
     """Heads-major q (b, h, s, d), k (b, kh, t, d) -> the log-sum-exp of each
     query's masked scores, f32 rows (b, h, 1, s)."""
@@ -364,7 +346,7 @@ def _lse_pass(q, k, causal, window, scale, block_q, block_k, interpret):
         out_specs=lse_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, 1, s), f32),
         scratch_shapes=[pltpu.VMEM((1, block_q), f32), pltpu.VMEM((1, block_q), f32)],
-        compiler_params=_BWD_PARAMS,
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(q, k)
 
@@ -389,7 +371,7 @@ def _fa_backward(q, k, v, do, di, causal, window, scale, block_q, block_k, inter
         # f32: a bf16 output of q's shape would read as the forward kernel
         out_shape=jax.ShapeDtypeStruct(q.shape, f32),
         scratch_shapes=[pltpu.VMEM((block_q, d), f32)],
-        compiler_params=_BWD_PARAMS,
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(q, k, v, do, lse, di)
 
@@ -417,7 +399,7 @@ def _fa_backward(q, k, v, do, di, causal, window, scale, block_q, block_k, inter
         out_specs=[k_spec, k_spec],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype)] * 2,
         scratch_shapes=[pltpu.VMEM((block_k, d), f32), pltpu.VMEM((block_k, d), f32)],
-        compiler_params=_BWD_PARAMS,
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(q, k, v, do, lse, di)
     return dq, dk, dv
@@ -428,21 +410,21 @@ def _heads_major(x):
     return x.transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, causal, window, scale, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, window, scale, interpret):
     out = _fa_forward(
-        _heads_major(q), _heads_major(k), _heads_major(v),
-        causal, window, scale, block_q, block_k, interpret,
+        _heads_major(q), _heads_major(k), _heads_major(v), causal, window, scale,
+        _block(q.shape[1], _FWD_BLOCK), _block(k.shape[1], _FWD_BLOCK), interpret,
     )
     return _heads_major(out)
 
 
-def _flash_fwd(q, k, v, causal, window, scale, block_q, block_k, interpret):
-    out = _flash(q, k, v, causal, window, scale, block_q, block_k, interpret)
+def _flash_fwd(q, k, v, causal, window, scale, interpret):
+    out = _flash(q, k, v, causal, window, scale, interpret)
     return out, (q, k, v, out)
 
 
-def _flash_bwd(causal, window, scale, block_q, block_k, interpret, res, g):
+def _flash_bwd(causal, window, scale, interpret, res, g):
     q, k, v, o = res
     with jax.named_scope("flash_bwd"):
         # D = rowsum(dO ∘ O), f32, laid out as the kernels' lse rows
@@ -450,7 +432,7 @@ def _flash_bwd(causal, window, scale, block_q, block_k, interpret, res, g):
         di = di.transpose(0, 2, 1)[:, :, None, :]
         dq, dk, dv = _fa_backward(
             _heads_major(q), _heads_major(k), _heads_major(v), _heads_major(g), di,
-            causal, window, scale, _bwd_block(q.shape[1]), _bwd_block(k.shape[1]),
+            causal, window, scale, _block(q.shape[1], _BWD_BLOCK), _block(k.shape[1], _BWD_BLOCK),
             interpret,
         )
         return (
@@ -469,14 +451,8 @@ def flash_attention_pallas(
     causal: bool = True,
     window: int = 0,
     scale: float | None = None,
-    block_q: int = 128,
-    block_k: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
-    s, t, d = q.shape[1], k.shape[1], q.shape[-1]
     if scale is None:
-        scale = d**-0.5
-    block_q = min(block_q, s)
-    block_k = min(block_k, t)
-    assert s % block_q == 0 and t % block_k == 0, (s, t, block_q, block_k)
-    return _flash(q, k, v, causal, window, float(scale), block_q, block_k, interpret)
+        scale = q.shape[-1] ** -0.5
+    return _flash(q, k, v, causal, window, float(scale), interpret)

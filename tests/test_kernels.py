@@ -41,6 +41,10 @@ def _rand(shape, dtype=jnp.float32):
         (2, 256, 4, 1, 32, True, 128),  # MQA + sliding window
         (1, 128, 2, 2, 128, False, 0),
         (1, 384, 6, 3, 64, True, 0),  # GQA rep=2, 3 blocks
+        # blocks over 128, several live and dead (the forward's are 1024 here)
+        (1, 2048, 8, 2, 64, True, 0),  # GQA 4:1, d 64
+        (1, 3072, 2, 1, 64, True, 300),  # window edge inside a block pair
+        (1, 2048, 4, 4, 128, False, 0),
     ],
 )
 def test_flash_attention_sweep(b, s, h, kh, d, causal, window, dtype):
@@ -53,14 +57,60 @@ def test_flash_attention_sweep(b, s, h, kh, d, causal, window, dtype):
     )
 
 
-def test_flash_attention_cross_block_q_offset():
+@pytest.mark.parametrize(
+    "s,t",
+    [
+        (128, 256),
+        (1024, 1536),  # t - s = 512: not a multiple of the 1024-row q block
+    ],
+)
+def test_flash_attention_cross_block_q_offset(s, t):
     """t > s: right-aligned queries (continuation chunk)."""
-    q = _rand((1, 128, 2, 64))
-    k = _rand((1, 256, 2, 64))
-    v = _rand((1, 256, 2, 64))
+    q = _rand((1, s, 2, 64))
+    k = _rand((1, t, 2, 64))
+    v = _rand((1, t, 2, 64))
     out = flash_attention_pallas(q, k, v, causal=True, interpret=True)
     exp = ref.flash_attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), atol=2e-6)
+
+
+@pytest.mark.parametrize(
+    "s,t,bq,bk,causal,window",
+    [
+        (4096, 4096, 1024, 1024, True, 0),
+        (2048, 2048, 512, 256, True, 700),  # window edge inside a block pair
+        (2048, 2048, 256, 512, False, 1024),
+        (512, 1152, 256, 128, True, 0),  # q_offset 640
+        (256, 2048, 128, 256, True, 300),  # q_offset 1792 and a window
+    ],
+)
+def test_flash_forward_dead_blocks_fetch_a_live_neighbour(s, t, bq, bk, causal, window):
+    """The forward's k/v index map: a live (q block, k block) step fetches its
+    own k block; a dead one re-addresses a live block of its q block, the one
+    its neighbouring step fetches, so a q block's k/v DMAs are as many as its
+    live k blocks."""
+    from repro.kernels.flash_attention import _k_inner_specs
+
+    q = jax.ShapeDtypeStruct((1, 2, s, 64), jnp.float32)
+    k = jax.ShapeDtypeStruct((1, 1, t, 64), jnp.float32)
+    kv_map = _k_inner_specs(q, k, causal, window, bq, bk)[1].index_map
+    qpos, kpos = np.arange(s)[:, None] + (t - s), np.arange(t)[None, :]
+    keep = kpos <= qpos if causal else np.ones((s, t), bool)
+    if window > 0:
+        keep &= kpos > qpos - window
+    nq, nk = s // bq, t // bk
+    live = keep.reshape(nq, bq, nk, bk).any(axis=(1, 3))
+    assert live.any(axis=1).all() and not live.all()
+    for qi in range(nq):
+        fetched = [int(kv_map(0, 1, qi, ki)[2]) for ki in range(nk)]
+        own = np.flatnonzero(live[qi])
+        for ki, got in enumerate(fetched):
+            if live[qi, ki]:
+                assert got == ki
+            else:
+                assert got == own[0 if ki < own[0] else -1], (qi, ki, got)
+        dmas = 1 + sum(a != b for a, b in zip(fetched, fetched[1:]))
+        assert dmas == len(own)
 
 
 # ---------------------------------------------------------------------------
@@ -320,6 +370,8 @@ BF16_GRAD_TOL = 2e-2
         (1, 256, 768, 4, 2, 32, True, 128, jnp.float32),  # a k block no query sees
         (1, 384, 384, 4, 2, 128, True, 0, jnp.bfloat16),
         (1, 256, 512, 8, 2, 64, True, 96, jnp.bfloat16),
+        # forward blocks of 1024, backward of 512, the window's edge inside them
+        (1, 2048, 2048, 2, 1, 64, True, 700, jnp.float32),
     ],
 )
 def test_flash_attention_vjp_matches_reference_grad(b, s, t, h, kh, d, causal, window, dtype):
@@ -352,12 +404,12 @@ def test_flash_attention_vjp_matches_reference_grad(b, s, t, h, kh, d, causal, w
 def test_flash_lse_pass_matches_logsumexp(s, t, causal, window):
     """The backward's lse pass == logsumexp of the oracle's masked scores,
     rows a window leaves partly empty included."""
-    from repro.kernels.flash_attention import _bwd_block, _lse_pass
+    from repro.kernels.flash_attention import _BWD_BLOCK, _block, _lse_pass
 
     b, h, kh, d = 1, 4, 2, 32
     q, k = _rand((b, h, s, d)), _rand((b, kh, t, d))
     scale = d**-0.5
-    got = _lse_pass(q, k, causal, window, scale, _bwd_block(s), _bwd_block(t), True)
+    got = _lse_pass(q, k, causal, window, scale, _block(s, _BWD_BLOCK), _block(t, _BWD_BLOCK), True)
     scores = jnp.einsum("bhsd,bhtd->bhst", q * scale, jnp.repeat(k, h // kh, axis=1))
     qpos, kpos = jnp.arange(s)[:, None] + (t - s), jnp.arange(t)[None, :]
     keep = kpos <= qpos if causal else jnp.ones((s, t), bool)

@@ -124,10 +124,11 @@ def _gathered_kernel_operands(hlo: str) -> list[str]:
 
 
 # ---------------------------------------------------------------------------
-# attention: qwen3-1.7b (16 q / 8 kv heads, d=128) and gpt-1.7b (24, d=96)
+# attention: qwen3-1.7b (16 q / 8 kv heads, d=128), gpt-1.7b (24, d=96) and
+# lfm2-8b-a1b (32 q / 8 kv heads, d=64)
 # ---------------------------------------------------------------------------
 
-ATTN = {"qwen3-1.7b": (16, 8, 128), "gpt-1.7b": (24, 24, 96)}
+ATTN = {"qwen3-1.7b": (16, 8, 128), "gpt-1.7b": (24, 24, 96), "lfm2-8b-a1b": (32, 8, 64)}
 
 
 def _qkv(one_chip, heads, kv, d, b=4, s=1024):
